@@ -1,8 +1,10 @@
 """Sharded (per-device lane ownership) vs single-device fused execution.
 
 Runs in a SUBPROCESS with ``XLA_FLAGS=--xla_force_host_platform_device_
-count=8`` (the parent process has already imported jax with one device;
-device count is fixed at import). The inner run builds one store, plans
+count=8`` and ``JAX_PLATFORMS=cpu`` (the parent process has already
+imported jax with one device; device count is fixed at import). The
+child never starts an accelerator backend: a chip belongs to one
+process, and the parent may hold it. The inner run builds one store, plans
 once, and compares the fused single-device executor against the sharded
 one on the same cached plan:
 
@@ -35,8 +37,9 @@ OUT_JSON = "BENCH_sharding.json"
 
 
 def run(smoke: bool = False, out_json: str = OUT_JSON):
-    """Spawn the forced-8-device inner run and pass its output through."""
-    env = {**os.environ,
+    """Spawn the forced-8-device CPU inner run and pass its output
+    through."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
                          " --xla_force_host_platform_device_count="
                          f"{N_DEVICES}").strip()}

@@ -78,11 +78,6 @@ def cpu_calibrated_hw(graph_or_store, app=None, geom=GEOM, n_samples=12,
                 ts.append(time.perf_counter() - t0)
             samples.append((i, store.geom, kind, float(np.median(ts))))
     hw, diag = perf_model.calibrate_full(samples, perf_model.TPU_V5E)
-    # pin the utilization profiler's %-of-peak denominator to what this
-    # calibration believes the host can stream, so it persists with the
-    # spec instead of being re-derived from analytic defaults
-    hw = hw.clone(peak_bandwidth_gbps=(
-        perf_model.effective_peak_bandwidth_bps(hw) / 1e9))
     try:
         registry.put(DeviceSpec(
             device_kind=kind, geom_key=geometry_key(geom), hw=hw,

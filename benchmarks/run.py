@@ -105,6 +105,12 @@ def main() -> None:
         print(ledger.render_report(report))
         return      # non-blocking by design: the report is the product
 
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # one fixed in-checkout path (the path is part of the cache key)
+        import jax
+        jax.config.update("jax_compilation_cache_dir", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache"))
     if args.smoke:
         args.quick = True
     want = (None if args.only == "all"

@@ -53,20 +53,16 @@ def geometry_key(geom: Geometry) -> str:
 
 
 def default_device_kind() -> str:
-    """Best-effort device identity: jax backend + device kind when jax is
-    importable, host name otherwise. Calibrated constants are only
-    portable across devices that share this string."""
+    """Device identity: jax's ``device_kind`` of the first device plus
+    the host name. Calibrated constants are only portable across devices
+    that share this string. A backend that fails to start raises here;
+    it is never reported as a CPU."""
     import platform
 
-    host = platform.node() or "host"
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        kind = getattr(dev, "device_kind", None) or jax.default_backend()
-        return f"{kind}@{host}"
-    except Exception:
-        return f"cpu@{host}"
+    host = platform.node() or "host"
+    return f"{jax.devices()[0].device_kind}@{host}"
 
 
 @dataclasses.dataclass
@@ -80,16 +76,6 @@ class DeviceSpec:
     created_at: float = 0.0        # unix time of the calibration
     source: str = "analytic"       # "analytic" | "calibrated" | "bench"
     fit: Dict[str, Any] = dataclasses.field(default_factory=dict)
-
-    @property
-    def peak_bandwidth_gbps(self) -> float:
-        """The %-of-peak denominator this spec implies: the explicit
-        calibrated ``HW.peak_bandwidth_gbps`` when set, else the
-        bandwidth the fitted stream terms believe in
-        (:func:`~repro.core.perf_model.effective_peak_bandwidth_bps`).
-        The utilization profiler and the dashboard read peaks through
-        this so persisted specs and live executors agree."""
-        return perf_model.effective_peak_bandwidth_bps(self.hw) / 1e9
 
     def age_s(self, now: Optional[float] = None) -> float:
         if self.created_at <= 0:

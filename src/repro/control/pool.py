@@ -91,6 +91,14 @@ def _w_cache_put(key: tuple, store: GraphStore) -> None:
         _STORE_CACHE.popitem(last=False)
 
 
+def _w_cpu_only() -> None:
+    """Worker initializer: pin jax to the CPU before any backend starts.
+    Workers only run host numpy, and an accelerator belongs to one
+    process — the parent that executes plans on it."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _w_ping() -> bool:
     return True
 
@@ -199,7 +207,8 @@ class WorkerPool:
             self.warm()
 
     def _spawn(self) -> ProcessPoolExecutor:
-        return ProcessPoolExecutor(max_workers=1, mp_context=self._ctx)
+        return ProcessPoolExecutor(max_workers=1, mp_context=self._ctx,
+                                   initializer=_w_cpu_only)
 
     def warm(self) -> None:
         """Block until every worker process is up (spawn cost is paid

@@ -40,26 +40,10 @@ from .executor import init_props
 from .gas import GATHER_IDENTITY
 from .types import BlockedEdges, Geometry
 
-# --- jax version compat ----------------------------------------------------
-# jax >= 0.6 promotes shard_map to jax.shard_map and replaces the old
-# replication checker with varying-manual-axes (pcast marks an array
-# varying). On the pinned 0.4.x line, shard_map lives in experimental and
-# check_rep=False plays the role of the explicit pcast.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-    _shard_map = partial(_exp_shard_map, check_rep=False)
-
-
 def _mark_varying(x, axis: str):
     """Tell the manual-axes checker the accumulator diverges across
-    devices once sharded chunks land (no-op where pcast is absent and
-    check_rep is off)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, (axis,), to="varying")
+    devices once sharded chunks land."""
+    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def _chunk_work(work: BlockedEdges, blocks_per_chunk: int) -> List[tuple]:
@@ -229,7 +213,7 @@ class DistributedEngine:
         combine = {"sum": jax.lax.psum, "or": jax.lax.psum,
                    "min": jax.lax.pmin, "max": jax.lax.pmax}[app.gather]
 
-        @partial(_shard_map, mesh=self.mesh,
+        @partial(jax.shard_map, mesh=self.mesh,
                  in_specs=(P(), P(axis), P(axis)), out_specs=P())
         def gather_phase(vprops, little_stack, big_stack):
             # local shard keeps a leading device axis of size 1 — drop it

@@ -84,8 +84,9 @@ class Executor:
              every app on the same plan shares device memory.
     app:     the :class:`~.gas.GASApp` whose scatter/gather/apply UDFs
              bind at run time.
-    path:    kernel path — "pallas" (compiled on TPU, interpret
-             elsewhere) or "ref" (pure-jnp oracle; the CPU default).
+    path:    kernel path — "pallas" (Mosaic-compiled on a TPU, Pallas
+             interpret mode on hosts without one) or "ref" (pure-jnp
+             oracle; the default off-TPU).
     fuse_lanes: True (default) runs each lane as ONE packed kernel
              launch; False launches per plan entry. Both paths are
              bit-identical (they share the single-merge program
@@ -121,8 +122,11 @@ class Executor:
         # (the A/B knob bench_profile's overhead gate exercises).
         self.profile = bool(profile)
         self.util = obs.UtilizationAccumulator(parent=util_parent)
-        self._peak_bps = perf_model.effective_peak_bandwidth_bps(
-            bundle.config.hw)
+        # %-of-peak denominator: the published HBM peak of the device
+        # this executor runs on; None (no utilization) for a device kind
+        # the table does not know
+        self._peak_bps = perf_model.device_peak_bandwidth_bps(
+            jax.devices()[0].device_kind)
         self._footprints = None  # lazy obs.lane_footprints
         self._lane_est = perf_model.lane_estimates(bundle.plan)
         # the estimate a measured iteration is compared against for the
@@ -153,6 +157,7 @@ class Executor:
             self._payloads = [p for lane in bundle.lane_entries()
                               for p in lane]
         self.t_materialize = time.perf_counter() - t0
+        self._arrays = [ops.device_arrays(p) for p in self._payloads]
 
         self.aux = store.aux
         self._iter_fn = None
@@ -202,7 +207,7 @@ class Executor:
             span.set(hbm_bytes=fp.hbm_bytes, flops=fp.flops,
                      gbps=round(gbps, 3))
         self.util.add(fp.kind, fp.hbm_bytes, fp.flops, measured_s,
-                      peak_bps=self._peak_bps, lane=lane_idx)
+                      peak_bps=self._peak_bps or 0.0, lane=lane_idx)
         return gbps
 
     def _run_payload(self, payload, vprops):
@@ -222,22 +227,39 @@ class Executor:
         per entry. Keeping the merge+apply region structurally identical
         is what makes the two paths bit-identical: XLA re-fuses
         value-equal scatter chains differently per program shape, which
-        shows up as 1-ULP drift in 'sum' apps."""
+        shows up as 1-ULP drift in 'sum' apps.
+
+        The payload arrays are ARGUMENTS (``arrays``, one dict per
+        payload, as :func:`~repro.kernels.ops.device_arrays` gives them),
+        not closure constants, so a multi-GB edge stream is never folded
+        into the lowered module."""
         app, geom = self.app, self.geom
         payloads = self._payloads
         ident = GATHER_IDENTITY[app.gather]
         dt = self.accum_dtype
 
-        def iteration(vprops, aux, it):
+        def iteration(vprops, aux, it, arrays):
             accum = jnp.full((self.V_pad,), ident, dt)
-            outs = [self._run_payload(p, vprops) for p in payloads]
+            outs = [self._run_payload({**p, **a}, vprops)
+                    for p, a in zip(payloads, arrays)]
             accum = ops.merge_all(accum, outs, geom.T)
             return app.apply(accum, vprops, aux, it)
 
         return iteration
 
     def _build_iteration(self):
-        return jax.jit(self._iteration_fn())
+        """``fn(vprops, aux, it)``: the jitted iteration with this
+        executor's payload arrays bound as arguments."""
+        fn = jax.jit(self._iteration_fn())
+        arrays = self._arrays
+        return lambda vprops, aux, it: fn(vprops, aux, it, arrays)
+
+    def lower_iteration(self):
+        """Lower the fused iteration exactly as :meth:`run` compiles it;
+        ``.as_text()`` of the result shows which kernels it launches
+        (``tpu_custom_call`` for Mosaic-compiled Pallas)."""
+        return jax.jit(self._iteration_fn()).lower(
+            self.init_props(), self.aux, 0, self._arrays)
 
     def init_props(self):
         return init_props(self.store, self.app)
@@ -456,7 +478,7 @@ class Executor:
         fn = self._iteration_fn()
         vprops = self.init_props()
         t0 = time.perf_counter()
-        jaxpr = jax.make_jaxpr(fn)(vprops, self.aux, 0)
+        jaxpr = jax.make_jaxpr(fn)(vprops, self.aux, 0, self._arrays)
         t_trace = time.perf_counter() - t0
         return {
             "jaxpr_eqns": _count_jaxpr_eqns(jaxpr.jaxpr),
@@ -470,7 +492,8 @@ class Executor:
         ``kinds``/``lanes`` until a traced run or ``time_lanes`` sweep
         has produced measured samples."""
         rep = self.util.report()
-        rep["peak_bandwidth_gbps"] = self._peak_bps / 1e9
+        rep["peak_bandwidth_gbps"] = (self._peak_bps / 1e9
+                                      if self._peak_bps else None)
         rep["profile"] = self.profile
         rep["footprints"] = [fp.as_dict() if fp is not None else None
                              for fp in (self.footprints()

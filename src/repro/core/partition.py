@@ -142,15 +142,11 @@ def _block_groups(src_sorted, dst_sorted, w_sorted, win_of_edge, tile_of_edge,
     wts[pos] = w_sorted
     valid[pos] = True
 
-    blk_win = np.zeros(n_blocks, np.int32)
-    blk_tile = np.zeros(n_blocks, np.int32)
-    # block index of the first block of each group
-    grp_blk_start = np.concatenate([[0], np.cumsum(blocks_per_group)])[:-1]
-    for gi in range(n_groups):
-        b0, nb = int(grp_blk_start[gi]), int(blocks_per_group[gi])
-        e0 = int(grp_starts[gi])
-        blk_win[b0:b0 + nb] = win_of_edge[e0]
-        blk_tile[b0:b0 + nb] = tile_of_edge[e0]
+    # every block of a group carries the group's (window, tile)
+    blk_win = np.repeat(win_of_edge[grp_starts],
+                        blocks_per_group).astype(np.int32)
+    blk_tile = np.repeat(tile_of_edge[grp_starts],
+                         blocks_per_group).astype(np.int32)
     return (src_l.reshape(n_blocks, E_BLK), dst_l.reshape(n_blocks, E_BLK),
             wts.reshape(n_blocks, E_BLK), valid.reshape(n_blocks, E_BLK),
             blk_win, blk_tile)

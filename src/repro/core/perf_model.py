@@ -21,7 +21,7 @@ the paper's approach of benchmarking memory latency to fit a and b.
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterable, List, Sequence, Tuple
+from typing import Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -51,12 +51,6 @@ class HW:
     # bit-identical, just more launches. Device specs set this (guide:
     # ~16 MB VMEM per TPU core); the analytic default leaves it off.
     vmem_lane_budget: float = 0.0
-    # achievable device bandwidth in GB/s, the denominator of the
-    # utilization profiler's %-of-peak (repro.obs.profile). 0 = derive
-    # from the stream terms via effective_peak_bandwidth_bps(); set
-    # explicitly by calibration (bench specs, retuner) so it persists
-    # through the autotune spec registry.
-    peak_bandwidth_gbps: float = 0.0
 
     def clone(self, **kw) -> "HW":
         return dataclasses.replace(self, **kw)
@@ -74,16 +68,20 @@ S_EDGE = 12          # src + dst + weight, 4 B each
 S_PROP = 4           # scalar f32/int32 property
 
 
-def effective_peak_bandwidth_bps(hw: HW) -> float:
-    """The bandwidth ceiling (bytes/s) the utilization profiler divides
-    achieved GB/s by. An explicitly calibrated ``peak_bandwidth_gbps``
-    wins; otherwise the base stream rate deflated by the calibrated
-    edge-stream multiplier — ``c_edges`` scales modelled *time*, so the
-    bandwidth the model believes this device sustains on the dominant
-    (edge) stream is ``bw_hbm / c_edges``."""
-    if hw.peak_bandwidth_gbps > 0:
-        return hw.peak_bandwidth_gbps * 1e9
-    return hw.bw_hbm / max(hw.c_edges, 1e-9)
+# Published per-chip peaks, keyed by jax's ``device_kind``. Source:
+# Google Cloud TPU documentation, "TPU v5e" (819 GB/s HBM, 197 TFLOP/s
+# bf16, 16 GB HBM per chip).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12},
+}
+
+
+def device_peak_bandwidth_bps(device_kind: str) -> Optional[float]:
+    """Published HBM bandwidth of a device kind (bytes/s), or None when
+    the kind is not in :data:`DEVICE_PEAKS` — an unknown device gets no
+    utilization figure rather than another chip's peak."""
+    peak = DEVICE_PEAKS.get(device_kind)
+    return None if peak is None else peak["hbm_bytes_per_s"]
 
 
 def _terms(info: PartitionInfo, geom: Geometry, kind: str, hw: HW):

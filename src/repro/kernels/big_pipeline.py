@@ -17,7 +17,7 @@ from .gas_kernel import gas_pallas_call, gas_pallas_call_segmented
 
 def big_pipeline(vprops_padded, unique_src, src_local, dst_local, weights,
                  valid, window_id, tile_id, tile_first, *, scatter_fn, mode,
-                 geom, n_out_tiles, interpret=True):
+                 geom, n_out_tiles, interpret):
     """Run one sparse-batch slice.
 
     unique_src: (n_unique_pad,) int32 global ids (the dedup'd request set).
@@ -37,7 +37,7 @@ def big_pipeline(vprops_padded, unique_src, src_local, dst_local, weights,
 def big_pipeline_packed(vprops_padded, unique_src, src_local, dst_local,
                         weights, valid, window_id, tile_id, tile_first, *,
                         scatter_fn, mode, geom, n_out_tiles, n_segments,
-                        interpret=True):
+                        interpret):
     """Run a whole packed Big lane (all sparse entries of one lane) as
     ONE segmented grid.
 

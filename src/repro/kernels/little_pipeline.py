@@ -15,7 +15,7 @@ from .gas_kernel import gas_pallas_call, gas_pallas_call_segmented
 
 def little_pipeline(vprops_padded, src_local, dst_local, weights, valid,
                     window_id, tile_id, tile_first, *, scatter_fn, mode,
-                    geom, n_out_tiles, interpret=True):
+                    geom, n_out_tiles, interpret):
     """Run one dense-partition slice.
 
     vprops_padded: (V_pad,) current vertex properties, V_pad % W == 0.
@@ -35,7 +35,7 @@ def little_pipeline(vprops_padded, src_local, dst_local, weights, valid,
 def little_pipeline_packed(vprops_padded, src_local, dst_local, weights,
                            valid, window_id, tile_id, tile_first, *,
                            scatter_fn, mode, geom, n_out_tiles, n_segments,
-                           interpret=True):
+                           interpret):
     """Run a whole packed Little lane (all dense entries of one lane,
     concatenated by ops.pack_lane) as ONE segmented grid. Window ids
     index the raw vprops windows, so packing needs no rebase here —

@@ -15,9 +15,15 @@ shot. ``run_lane`` then executes an entire lane as ONE ``pallas_call``
 dispatches and trace size scale with the number of lanes, not the
 number of materialized plan entries.
 
-``run_entry`` dispatches to the Pallas kernel (interpret=True on CPU,
-compiled on TPU) or the pure-jnp reference path — identical math, used
-both as the CPU fast path and as the oracle.
+``run_entry`` dispatches to the Pallas kernel (compiled by Mosaic on a
+TPU, Pallas interpret mode on hosts without one) or the pure-jnp
+reference path — identical math, used both as the CPU fast path and as
+the oracle.
+
+Device payloads keep the per-block edge fields (``_BLOCK_KEYS``) in the
+kernel's 3-D block layout ``(n_blocks, 1, E_BLK)`` (see
+``gas_kernel``), so the compiled kernel reads them without a relayout;
+the reference path views them as ``(n_blocks, E_BLK)``.
 """
 from __future__ import annotations
 
@@ -38,10 +44,27 @@ _CONCAT_KEYS = ("src_local", "dst_local", "weights", "valid",
                 "window_id", "tile_id", "tile_first", "tile_idx")
 # payload keys uploaded to the device by _upload_payload
 _DEVICE_KEYS = _CONCAT_KEYS + ("unique_src",)
+# per-block edge fields, uploaded as (n_blocks, 1, E_BLK)
+_BLOCK_KEYS = ("src_local", "dst_local", "weights", "valid")
 
 
 def default_path() -> str:
+    """Kernel path when the caller names none: compiled Pallas on a TPU,
+    the jnp reference elsewhere."""
     return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
+def interpret_mode() -> bool:
+    """Pallas interpret mode only where there is no TPU to compile for;
+    on a TPU the "pallas" path always runs Mosaic-compiled kernels."""
+    return jax.default_backend() != "tpu"
+
+
+def device_arrays(payload: dict) -> dict:
+    """The device-array fields of one payload — what a jitted iteration
+    takes as arguments (the rest is static metadata)."""
+    return {k: payload[k] for k in _DEVICE_KEYS
+            if payload.get(k) is not None}
 
 
 def snap_down(blocked: BlockedEdges, x: int) -> int:
@@ -103,8 +126,11 @@ def _upload_payload(p: dict, device=None) -> dict:
     out = dict(p)
     for k in _DEVICE_KEYS:
         if out.get(k) is not None:
-            out[k] = (jnp.asarray(out[k]) if device is None
-                      else jax.device_put(np.asarray(out[k]), device))
+            x = np.asarray(out[k])
+            if k in _BLOCK_KEYS:
+                x = x.reshape(x.shape[0], 1, x.shape[-1])
+            out[k] = (jnp.asarray(x) if device is None
+                      else jax.device_put(x, device))
     return out
 
 
@@ -434,14 +460,21 @@ def payload_nbytes(payload: dict) -> int:
 # Execution
 # ---------------------------------------------------------------------------
 
+def _kernel_args(p: dict, path: str) -> tuple:
+    """The seven per-block kernel operands of one payload; the reference
+    path views the 3-D edge fields as (n_blocks, E_BLK)."""
+    blocks = [p[k] for k in _BLOCK_KEYS]
+    if path == "ref":
+        blocks = [x.reshape(x.shape[0], x.shape[-1]) for x in blocks]
+    return (*blocks, p["window_id"], p["tile_id"], p["tile_first"])
+
+
 def run_entry(entry: dict, vprops_padded, scatter_fn, mode: str,
               path: Optional[str] = None):
     """Returns (tiles (n_out_tiles, T), tile_idx (n_out_tiles,))."""
     path = path or default_path()
     geom: Geometry = entry["geom"]
-    args = (entry["src_local"], entry["dst_local"], entry["weights"],
-            entry["valid"], entry["window_id"], entry["tile_id"],
-            entry["tile_first"])
+    args = _kernel_args(entry, path)
     if path == "ref":
         if entry["kind"] == "big":
             vwin = vprops_padded[entry["unique_src"]].reshape(-1, geom.W)
@@ -450,7 +483,7 @@ def run_entry(entry: dict, vprops_padded, scatter_fn, mode: str,
         tiles = ref_mod.gas_ref(vwin, *args, scatter_fn=scatter_fn, mode=mode,
                                 t=geom.T, n_out_tiles=entry["n_out_tiles"])
     else:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
         if entry["kind"] == "big":
             tiles = big_pipeline(vprops_padded, entry["unique_src"], *args,
                                  scatter_fn=scatter_fn, mode=mode, geom=geom,
@@ -472,9 +505,7 @@ def run_lane(packed: dict, vprops_padded, scatter_fn, mode: str,
     returns (tiles (n_out_tiles, T), tile_idx (n_out_tiles,))."""
     path = path or default_path()
     geom: Geometry = packed["geom"]
-    args = (packed["src_local"], packed["dst_local"], packed["weights"],
-            packed["valid"], packed["window_id"], packed["tile_id"],
-            packed["tile_first"])
+    args = _kernel_args(packed, path)
     if path == "ref":
         if packed["kind"] == "big":
             vwin = vprops_padded[packed["unique_src"]].reshape(-1, geom.W)
@@ -483,7 +514,7 @@ def run_lane(packed: dict, vprops_padded, scatter_fn, mode: str,
         tiles = ref_mod.gas_ref(vwin, *args, scatter_fn=scatter_fn, mode=mode,
                                 t=geom.T, n_out_tiles=packed["n_out_tiles"])
     else:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
         kw = dict(scatter_fn=scatter_fn, mode=mode, geom=geom,
                   n_out_tiles=packed["n_out_tiles"],
                   n_segments=packed["n_entries"], interpret=interpret)

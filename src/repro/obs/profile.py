@@ -28,10 +28,10 @@ fraction of the device peak. This module closes that gap for the repro:
   the ``regraph_lane_bandwidth_gbps`` / ``regraph_pipeline_utilization``
   Prometheus gauges, and the control-plane dashboard's per-lane bars.
 
-The %-of-peak denominator is ``HW.peak_bandwidth_gbps`` (calibrated,
-persisted through the autotune spec registry) falling back to
-``perf_model.effective_peak_bandwidth_bps`` — see docs/OBSERVABILITY.md
-for the formulas.
+The %-of-peak denominator is the running device's published HBM peak
+(``perf_model.DEVICE_PEAKS``, keyed by ``device_kind``); an unknown
+device kind reports no utilization — see docs/OBSERVABILITY.md for the
+formulas.
 """
 from __future__ import annotations
 
@@ -186,9 +186,9 @@ class UtilizationAccumulator:
     ``parent=``, and :meth:`report` renders the utilization block that
     ``stats()``, the Prometheus gauges and the dashboard read.
 
-    A sample's ``peak_bps`` (the executor's HW-derived bandwidth
-    ceiling) rides along so %-of-peak is computed against the spec the
-    lane actually ran under, not a global constant.
+    A sample's ``peak_bps`` (the published HBM peak of the device the
+    lane ran on; 0 when unknown) rides along so %-of-peak is computed
+    against the device the lane actually ran on.
     """
 
     # per-lane last-sample retention bound (lanes × kinds is small, but

@@ -479,9 +479,9 @@ class ServiceMetrics:
                [((("kind", k),), rep.get("gbps"))
                 for k, rep in sorted(util_kinds.items())])
         metric("pipeline_utilization", "gauge",
-               "Achieved bandwidth as a fraction of the calibrated "
-               "device peak (HW.peak_bandwidth_gbps), per pipeline "
-               "kind.",
+               "Achieved bandwidth as a fraction of the device's "
+               "published HBM peak (perf_model.DEVICE_PEAKS), per "
+               "pipeline kind.",
                [((("kind", k),), rep.get("utilization"))
                 for k, rep in sorted(util_kinds.items())])
         metric("retunes_total", "counter",

@@ -213,10 +213,12 @@ class ShardedExecutor:
     def _build(self) -> None:
         """Build the per-device local fns and the merge+apply fn.
 
-        Each device fn closes over its resident payloads; calling it
-        with vprops committed to the same device executes there (no
-        implicit transfers — jax refuses mixed-device jit inputs, which
-        doubles as an assertion that payloads really are resident). It
+        Each device fn takes its resident payload arrays as jit
+        arguments (bound here, never folded into the lowered module);
+        calling it with vprops committed to the same device executes
+        there (no implicit transfers — jax refuses mixed-device jit
+        inputs, which doubles as an assertion that payloads really are
+        resident). It
         returns the device's concatenated output tiles + global tile
         indices; the merge+apply fn scatter-sets them all at once — the
         same ``merge_all`` + Apply program region the fused
@@ -228,12 +230,15 @@ class ShardedExecutor:
         V_pad, path = self.V_pad, self.path
 
         def make_dev_fn(payloads):
-            def local(vprops):
-                outs = [ops.run_lane(p, vprops, app.scatter, app.gather,
-                                     path) for p in payloads]
+            def local(vprops, arrays):
+                outs = [ops.run_lane({**p, **a}, vprops, app.scatter,
+                                     app.gather, path)
+                        for p, a in zip(payloads, arrays)]
                 return (jnp.concatenate([o[0] for o in outs]),
                         jnp.concatenate([o[1] for o in outs]))
-            return jax.jit(local)
+            fn = jax.jit(local)
+            arrays = [ops.device_arrays(p) for p in payloads]
+            return lambda vprops: fn(vprops, arrays)
 
         self._dev_fns = [make_dev_fn(ps) if ps else None
                          for ps in self._dev_payloads]

@@ -176,6 +176,14 @@ class TestWorkerPool:
             assert np.array_equal(clone.edges[k], st.edges[k])
         assert clone.plan(PlanConfig()).plan is not None    # rebuildable
 
+    def test_worker_never_starts_an_accelerator_backend(self, monkeypatch):
+        """Workers inherit the parent's environment; even one that asks
+        for a TPU must come up on the CPU (the chip is the parent's)."""
+        import jax
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        with WorkerPool(workers=1) as p:
+            assert p._run(0, jax.default_backend) == "cpu"
+
     def test_build_store_matches_local(self, pool, g1):
         st = pool.build_store(g1, geom=GEOM, use_dbg=True,
                               fp=g1.fingerprint())

@@ -1,4 +1,4 @@
-"""Multi-device tests — each spawns a subprocess with
+"""Multi-device tests — each spawns a CPU-only subprocess with
 XLA_FLAGS=--xla_force_host_platform_device_count=8 so the main test
 process keeps seeing exactly one device."""
 import os
@@ -6,24 +6,10 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
-ENV = {**os.environ,
+ENV = {**os.environ, "JAX_PLATFORMS": "cpu",
        "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
        "PYTHONPATH": os.path.abspath(
            os.path.join(os.path.dirname(__file__), "..", "src"))}
-
-# The LM-side sharding tests are written against the jax>=0.6 mesh API
-# (jax.shard_map, jax.sharding.AxisType, make_mesh axis_types). The graph
-# engine's own distributed path ships a 0.4.x compat shim
-# (core/distributed.py), but porting the off-paper LM/optimizer sharding
-# stack is not worth it on the pinned 0.4.x line.
-NEEDS_JAX06 = pytest.mark.xfail(
-    not (hasattr(jax, "shard_map") and hasattr(jax.sharding, "AxisType")),
-    reason="needs jax>=0.6 sharding APIs (jax.shard_map, "
-           "jax.sharding.AxisType); pinned jax is 0.4.x",
-    strict=False)
 
 
 def run_py(code: str, timeout=600):
@@ -62,7 +48,6 @@ def test_distributed_graph_engine_matches_single():
     """)
 
 
-@NEEDS_JAX06
 def test_sharded_train_step_matches_single_device():
     run_py("""
         import numpy as np, jax, jax.numpy as jnp, dataclasses
@@ -102,7 +87,6 @@ def test_sharded_train_step_matches_single_device():
     """)
 
 
-@NEEDS_JAX06
 def test_sharded_moe_matches_local():
     run_py("""
         import numpy as np, jax, jax.numpy as jnp, dataclasses
@@ -127,7 +111,6 @@ def test_sharded_moe_matches_local():
     """)
 
 
-@NEEDS_JAX06
 def test_compressed_psum_cross_pod():
     run_py("""
         import numpy as np, jax, jax.numpy as jnp
@@ -153,7 +136,6 @@ def test_compressed_psum_cross_pod():
     """)
 
 
-@NEEDS_JAX06
 def test_elastic_checkpoint_restore_new_mesh(tmp_path):
     run_py(f"""
         import numpy as np, jax, jax.numpy as jnp

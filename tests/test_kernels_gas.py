@@ -129,3 +129,33 @@ def test_kernel_geometry_sweep(e_blk, w, t, rng):
                             n_out_tiles=n_tiles)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("mode", ["sum", "min", "or"])
+def test_chunked_grids_bit_identical(mode, rng):
+    """A payload split into several grids (the SMEM prefetch-table bound)
+    resumes tiles cut at grid boundaries from the previous grid's flush:
+    bit-identical to a single grid, including tiles spanning 3 grids."""
+    e_blk, w, t, n_blocks, n_win, n_tiles = 128, 512, 512, 11, 3, 3
+    if mode == "or":
+        vwin = rng.randint(-2**31, 2**31 - 1, (n_win, w), dtype=np.int64)
+        vwin = jnp.asarray(vwin.astype(np.int32))
+    else:
+        vwin = jnp.asarray(rng.rand(n_win, w).astype(np.float32))
+    src = jnp.asarray(rng.randint(0, w, (n_blocks, e_blk)).astype(np.int32))
+    dst = jnp.asarray(rng.randint(0, 40, (n_blocks, e_blk)).astype(np.int32))
+    wts = jnp.asarray(rng.rand(n_blocks, e_blk).astype(np.float32))
+    valid = jnp.asarray(rng.rand(n_blocks, e_blk) < 0.9, jnp.int32)
+    wid = jnp.asarray(rng.randint(0, n_win, n_blocks).astype(np.int32))
+    tid = np.array([0, 0, 0, 0, 0, 0, 1, 1, 2, 2, 2], np.int32)
+    tf = np.ones(n_blocks, np.int32)
+    tf[1:] = tid[1:] != tid[:-1]
+    args = (vwin, src, dst, wts, valid, wid, jnp.asarray(tid),
+            jnp.asarray(tf))
+    sc = (lambda p, wt: p) if mode != "min" else (lambda p, wt: p + wt)
+    kw = dict(scatter_fn=sc, mode=mode, e_blk=e_blk, w=w, t=t,
+              n_out_tiles=n_tiles, interpret=True)
+    one = np.asarray(gas_pallas_call(*args, **kw))
+    for chunk in (1, 2, 4):
+        got = np.asarray(gas_pallas_call(*args, **kw, max_grid_blocks=chunk))
+        assert got.tobytes() == one.tobytes(), chunk

@@ -10,12 +10,14 @@ import re
 import time
 import urllib.request
 
+import jax
 import pytest
 
 from repro import api, obs
 from repro.control import ControlPlane, JobStore, WorkerPool
 from repro.control.dashboard import DASHBOARD_HTML
 from repro.control.jobs import JobState
+from repro.core import perf_model
 from repro.core.types import Geometry
 from repro.graphs.rmat import rmat
 from repro.obs.ledger import PerfLedger, flatten_metrics, git_sha
@@ -79,7 +81,16 @@ class TestLaneFootprints:
         assert util["kinds"], "traced run must record samples"
         for rep in util["kinds"].values():
             assert rep["gbps"] > 0 and rep["n"] > 0
-        assert util["peak_bandwidth_gbps"] > 0
+        # the denominator is the running device's published peak; a
+        # device kind without one (the CPU) reports no utilization
+        peak = perf_model.device_peak_bandwidth_bps(
+            jax.devices()[0].device_kind)
+        if peak is None:
+            assert util["peak_bandwidth_gbps"] is None
+            assert all(rep["utilization"] is None
+                       for rep in util["kinds"].values())
+        else:
+            assert util["peak_bandwidth_gbps"] == pytest.approx(peak / 1e9)
         # exec.lane spans carry the footprint counters
         spans = [s for s in tr.export(root.trace_id)
                  if s["name"] == "executor.lane"]
@@ -119,6 +130,11 @@ class TestUtilizationAccumulator:
         assert little["intensity"] == pytest.approx(2.0)
         assert rep["peak_bandwidth_gbps"] == pytest.approx(4.0)
         assert rep["lanes"][0]["kind"] == "little"
+
+    def test_device_peak_table(self):
+        assert perf_model.device_peak_bandwidth_bps("TPU v5 lite") == 819e9
+        assert perf_model.device_peak_bandwidth_bps("cpu") is None
+        assert perf_model.device_peak_bandwidth_bps("TPU v9 made up") is None
 
     def test_no_peak_means_none_utilization(self):
         acc = UtilizationAccumulator()
