@@ -1,4 +1,4 @@
-"""Utilization-profiler gates — footprint truth, overhead, export, ledger.
+"""Utilization-profiler gates — footprint truth, overhead, export.
 
 The profiler (repro.obs.profile) is only worth shipping if its numbers
 are *trustworthy* and its cost is *invisible*, so this suite gates:
@@ -15,17 +15,12 @@ are *trustworthy* and its cost is *invisible*, so this suite gates:
      ``regraph_lane_bandwidth_gbps`` / ``regraph_pipeline_utilization``
      samples on ``GET /metrics``, the ``/dashboard`` page serves, and
      ``/readyz`` reports ready.
-  4. **ledger round-trip** — a PerfLedger append is read back by
-     ``compare`` (first record: no history, nothing flagged; a planted
-     regression on a second sha IS flagged).
 
 Results go to stdout as CSV AND to ``BENCH_profile.json``.
 """
 from __future__ import annotations
 
 import json
-import os
-import tempfile
 import time
 import urllib.request
 
@@ -35,7 +30,6 @@ from repro import api, obs
 from repro.core import gas
 from repro.core.executor import Executor
 from repro.graphs import datasets
-from repro.obs.ledger import PerfLedger
 
 from .common import GEOM, cpu_calibrated_hw, emit, store_for
 
@@ -135,30 +129,6 @@ def _gate_export(g) -> dict:
                 "dashboard_bytes": len(dhtml), "readyz": ready}
 
 
-def _gate_ledger() -> dict:
-    fd, path = tempfile.mkstemp(suffix=".jsonl")
-    os.close(fd)
-    try:
-        led = PerfLedger(path)
-        led.append("profile", {"p50_on_s": 0.010, "gbps": 5.0},
-                   sha="aaaa", geom_key="g", spec_version=1)
-        first = led.compare()
-        assert first["benches"]["profile"]["n_prior"] == 0
-        assert first["regressions"] == 0
-        # a planted 2x latency regression on the next sha must flag
-        led.append("profile", {"p50_on_s": 0.020, "gbps": 5.0},
-                   sha="bbbb", geom_key="g", spec_version=1)
-        second = led.compare()
-        entry = second["benches"]["profile"]
-        assert entry["n_prior"] == 1 and second["regressions"] == 1, second
-        flagged = {f["metric"] for f in entry["flagged"]}
-        assert "p50_on_s" in flagged and "gbps" not in flagged
-        return {"records": len(led.records()),
-                "regressions_flagged": second["regressions"]}
-    finally:
-        os.unlink(path)
-
-
 def run(graphs=None, rounds=9, iters=2, out_json="BENCH_profile.json"):
     graphs = graphs or ["ggs"]
     records = []
@@ -183,17 +153,13 @@ def run(graphs=None, rounds=9, iters=2, out_json="BENCH_profile.json"):
     emit("profile.export", 0.0,
          f"{export['bandwidth_samples']} bandwidth samples on /metrics; "
          f"dashboard+readyz ok")
-    ledger = _gate_ledger()
-    emit("profile.ledger", 0.0,
-         f"round-trip ok, {ledger['regressions_flagged']} planted "
-         f"regression flagged")
     if out_json:
         with open(out_json, "w") as f:
             json.dump({"benchmark": "utilization_profiler",
                        "gate_bytes": GATE_BYTES,
                        "gate_overhead": GATE_OVERHEAD,
-                       "records": records, "export": export,
-                       "ledger": ledger}, f, indent=2)
+                       "records": records, "export": export}, f,
+                      indent=2)
         emit("profile.artifact", 0.0, out_json)
     emit("profile.gate", 0.0, "pass")
     return records

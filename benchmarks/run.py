@@ -14,72 +14,19 @@ Prints ``name,us_per_call,derived`` CSV lines (see benchmarks/common.emit).
   —        bench_control_plane  p99 update latency, threads vs pool (+ JSON)
   —        bench_obs            tracing-off vs tracing-on overhead (+ JSON)
   —        bench_autotune       calibrate-and-replan gates (+ JSON)
-  —        bench_profile        utilization profiler + ledger gates (+ JSON)
+  —        bench_profile        utilization profiler gates (+ JSON)
 
-Every suite that writes a ``BENCH_*.json`` artifact also APPENDS its
-flattened gate metrics to the perf ledger (``BENCH_ledger.jsonl``,
-``--ledger`` to move, ``--ledger ''`` to disable), keyed by git sha /
-geometry / bench device-spec version. ``run.py compare`` reports the
-latest records against the rolling median of prior ones — a
-non-blocking CI step (always exit 0; the report is the product).
+The chip benchmark of record is ``bench/run.py`` (``BENCHMARK.json``);
+these suites time CPU or interpret-mode runs.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import time
 
-# suite name -> the JSON artifact its run() writes (ledger source)
-ARTIFACTS = {
-    "fused": "BENCH_fused.json",
-    "streaming": "BENCH_streaming.json",
-    "sharding": "BENCH_sharding.json",
-    "control_plane": "BENCH_control_plane.json",
-    "obs": "BENCH_obs.json",
-    "autotune": "BENCH_autotune.json",
-    "profile": "BENCH_profile.json",
-}
-
-
-def _ledger_context():
-    """(geom_key, bench spec version | None) for ledger records — the
-    same key the calibration cache uses, so records are comparable only
-    within one device/geometry lineage."""
-    from repro.autotune import (SpecRegistry, default_device_kind,
-                                geometry_key)
-
-    from .common import GEOM
-    gkey = geometry_key(GEOM)
-    spec = SpecRegistry().get("bench-" + default_device_kind(), GEOM)
-    return gkey, (spec.version if spec is not None else None)
-
-
-def _append_ledger(ledger, suite: str, artifact: str,
-                   run_started: float, geom_key, spec_version) -> None:
-    """Fold one suite's fresh artifact into the ledger (best-effort:
-    a stale or unreadable artifact is skipped, never fatal)."""
-    from repro.obs.ledger import flatten_metrics
-    try:
-        if os.path.getmtime(artifact) < run_started:
-            return      # suite didn't (re)write it this run
-        with open(artifact, "r", encoding="utf-8") as f:
-            doc = json.load(f)
-        ledger.append(suite, flatten_metrics(doc), geom_key=geom_key,
-                      spec_version=spec_version,
-                      meta={"artifact": artifact})
-        print(f"ledger.{suite},0,appended to {ledger.path}", flush=True)
-    except (OSError, ValueError) as exc:
-        print(f"ledger.{suite},0,skipped ({exc})", flush=True)
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("command", nargs="?", default="bench",
-                    choices=("bench", "compare"),
-                    help="bench (default): run suites and append the "
-                         "perf ledger; compare: report the latest "
-                         "ledger records vs their rolling median")
     ap.add_argument("--only", default="all",
                     help="comma list: pipelines,heterogeneity,scalability,"
                          "preprocessing,amortization,sota,roofline,serving,"
@@ -89,21 +36,7 @@ def main() -> None:
                     help="smaller graph set (CI-speed)")
     ap.add_argument("--smoke", action="store_true",
                     help="tiniest graphs (implies --quick; CI smoke tier)")
-    ap.add_argument("--ledger", default="BENCH_ledger.jsonl",
-                    help="perf ledger JSONL path ('' disables)")
-    ap.add_argument("--tolerance", type=float, default=None,
-                    help="compare: |relative change| that flags a metric "
-                         "(default 0.25)")
     args = ap.parse_args()
-
-    if args.command == "compare":
-        from repro.obs.ledger import DEFAULT_TOLERANCE, PerfLedger
-        ledger = PerfLedger(args.ledger or "BENCH_ledger.jsonl")
-        report = ledger.compare(
-            tolerance=(args.tolerance if args.tolerance is not None
-                       else DEFAULT_TOLERANCE))
-        print(ledger.render_report(report))
-        return      # non-blocking by design: the report is the product
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         # one fixed in-checkout path (the path is part of the cache key)
@@ -183,17 +116,11 @@ def main() -> None:
             rounds=3 if args.smoke else 5)),
         # gates the utilization profiler: analytic lane bytes within
         # ±10% of the jaxpr-derived count, profile-on p50 within 5%,
-        # gauges on /metrics, dashboard/readyz up, ledger round-trip
+        # gauges on /metrics, dashboard/readyz up
         ("profile", lambda: bench_profile.run(
             graphs=["ggs"] if args.quick else ["ggs", "hws"],
             rounds=5 if args.smoke else 9)),
     ]
-    ledger = None
-    geom_key = spec_version = None
-    if args.ledger:
-        from repro.obs.ledger import PerfLedger
-        ledger = PerfLedger(args.ledger)
-    run_started = time.time()
     print("name,us_per_call,derived")
     for name, fn in suites:
         if want and name not in want:
@@ -202,11 +129,6 @@ def main() -> None:
         fn()
         print(f"suite.{name},{(time.time() - t0) * 1e6:.0f},done",
               flush=True)
-        if ledger is not None and name in ARTIFACTS:
-            if geom_key is None:
-                geom_key, spec_version = _ledger_context()
-            _append_ledger(ledger, name, ARTIFACTS[name], run_started,
-                           geom_key, spec_version)
 
 
 if __name__ == "__main__":

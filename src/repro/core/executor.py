@@ -72,6 +72,12 @@ def _count_jaxpr_eqns(jaxpr) -> int:
     return n
 
 
+# the name of the fused iteration function in ``_iteration_fn``, which
+# JAX traces (``obs.JitCounts.traced``) and compiles (``jit_iteration``)
+# under
+ITERATION_PROGRAM = "iteration"
+
+
 class Executor:
     """Per-(plan, app) single-device executor.
 
@@ -299,34 +305,34 @@ class Executor:
         """One iteration under an active tracer with lane detail: a span
         per lane (carrying the model estimate, so every trace doubles as
         a calibration sample), one for merge+apply, drift samples for
-        both levels."""
+        both levels (``run`` opens the ``executor.iteration`` around
+        them)."""
         lane_fns, merge_apply = self._traced_fns
         est = self._lane_est
-        with obs.span("executor.iteration", "executor", it=it):
-            outs = []
-            for li, f in enumerate(lane_fns):
-                if f is None:
-                    continue
-                e_i, kind_i = est[li] if li < len(est) else (0.0, "mixed")
-                t0 = time.perf_counter()
-                n_entries = (len(self.plan.lanes[li])
-                             if li < len(self.plan.lanes) else 0)
-                with obs.span("executor.lane", "executor", lane=li,
-                              kind=kind_i, est_time=e_i,
-                              n_entries=n_entries) as lane_sp:
-                    lane_out = f(vprops)
-                    jax.block_until_ready(lane_out)
-                    measured = time.perf_counter() - t0
-                    # achieved-bandwidth counters ride on the span the
-                    # trace already carries (bytes are analytic, so the
-                    # only run-path cost is the divide + dict update)
-                    self._util_add(li, kind_i, measured, span=lane_sp)
-                self.drift.add(kind_i, e_i, measured)
-                self._calib_add(li, kind_i, measured)
-                outs.extend(lane_out)
-            with obs.span("executor.merge_apply", "executor", it=it):
-                new = merge_apply(vprops, outs, self.aux, it)
-                new.block_until_ready()
+        outs = []
+        for li, f in enumerate(lane_fns):
+            if f is None:
+                continue
+            e_i, kind_i = est[li] if li < len(est) else (0.0, "mixed")
+            t0 = time.perf_counter()
+            n_entries = (len(self.plan.lanes[li])
+                         if li < len(self.plan.lanes) else 0)
+            with obs.span("executor.lane", "executor", lane=li,
+                          kind=kind_i, est_time=e_i,
+                          n_entries=n_entries) as lane_sp:
+                lane_out = f(vprops)
+                jax.block_until_ready(lane_out)
+                measured = time.perf_counter() - t0
+                # achieved-bandwidth counters ride on the span the
+                # trace already carries (bytes are analytic, so the
+                # only run-path cost is the divide + dict update)
+                self._util_add(li, kind_i, measured, span=lane_sp)
+            self.drift.add(kind_i, e_i, measured)
+            self._calib_add(li, kind_i, measured)
+            outs.extend(lane_out)
+        with obs.span("executor.merge_apply", "executor", it=it):
+            new = merge_apply(vprops, outs, self.aux, it)
+            new.block_until_ready()
         return new
 
     def run(self, max_iters: Optional[int] = None, collect_history=False):
@@ -336,38 +342,68 @@ class Executor:
         iteration switches to the traced per-lane path (extra dispatches
         per iteration, bit-identical results — see
         :meth:`_build_traced_fns`); otherwise the single fused jit runs
-        and only the per-iteration makespan drift sample is taken."""
+        and only the per-iteration makespan drift sample is taken.
+
+        Spans (each also a profiler annotation, so a profiler trace
+        shows them with no tracer installed): ``executor.iteration``
+        per iteration, holding on the fused path ``executor.compile``
+        (the first call of a freshly built iteration jit),
+        ``executor.sync`` (the host waits for the device) and, on both
+        paths, ``executor.converged``; then ``executor.readback``."""
         tracer = obs.current_tracer()
         lane_detail = (tracer is not None and tracer.lane_detail
                        and obs.current_ctx() is not None)
+        fresh = False
         if lane_detail:
             if self._traced_fns is None:
                 self._traced_fns = self._build_traced_fns()
         elif self._iter_fn is None:
             self._iter_fn = self._build_iteration()
+            fresh = True
         vprops = self.init_props()
         iters = max_iters or self.app.max_iters
         est_makespan = self._est_iteration
         history = []
         it_done = 0
         for it in range(iters):
-            t_it = time.perf_counter()
-            if lane_detail:
-                new = self._run_iteration_traced(vprops, it)
-            else:
-                new = self._iter_fn(vprops, self.aux, it)
-                new.block_until_ready()
-            self.drift.add("makespan", est_makespan,
-                           time.perf_counter() - t_it)
+            with obs.span("executor.iteration", "executor", it=it):
+                t_it = time.perf_counter()
+                if lane_detail:
+                    new = self._run_iteration_traced(vprops, it)
+                else:
+                    if fresh:
+                        new = self._first_call(vprops, it)
+                        fresh = False
+                    else:
+                        new = self._iter_fn(vprops, self.aux, it)
+                    with obs.span("executor.sync", "executor"):
+                        new.block_until_ready()
+                self.drift.add("makespan", est_makespan,
+                               time.perf_counter() - t_it)
+                if collect_history:
+                    history.append(np.asarray(new))
+                with obs.span("executor.converged", "executor"):
+                    done = self.app.converged(vprops, new, it)
             it_done = it + 1
-            if collect_history:
-                history.append(np.asarray(new))
-            if self.app.converged(vprops, new, it):
-                vprops = new
-                break
             vprops = new
-        out = np.asarray(vprops)[self.store.perm]  # back to original ids
+            if done:
+                break
+        with obs.span("executor.readback", "executor"):
+            out = np.asarray(vprops)[self.store.perm]  # back to original ids
         return out, {"iterations": it_done, "history": history}
+
+    def _first_call(self, vprops, it):
+        """The first call of a freshly built iteration jit, under an
+        ``executor.compile`` span: jaxpr trace, lowering and the compile
+        or persistent-cache fetch, up to the dispatch's return. The span
+        carries what this thread traced and compiled meanwhile."""
+        c0 = obs.jitcount.thread_counts()
+        with obs.span("executor.compile", "executor") as sp:
+            new = self._iter_fn(vprops, self.aux, it)
+            d = obs.jitcount.thread_counts() - c0
+            sp.set(traces=d.traces, compiles=d.compiles,
+                   cache_hits=d.cache_hits, cache_misses=d.cache_misses)
+        return new
 
     # ------------------------------------------------------------------
     def time_iteration(self, repeats: int = 5) -> float:

@@ -31,7 +31,7 @@ def big_pipeline(vprops_padded, unique_src, src_local, dst_local, weights,
         window_id, tile_id, tile_first,
         scatter_fn=scatter_fn, mode=mode,
         e_blk=geom.E_BLK, w=geom.W, t=geom.T, n_out_tiles=n_out_tiles,
-        interpret=interpret)
+        interpret=interpret, pipeline="big")
 
 
 def big_pipeline_packed(vprops_padded, unique_src, src_local, dst_local,
@@ -55,4 +55,4 @@ def big_pipeline_packed(vprops_padded, unique_src, src_local, dst_local,
         window_id, tile_id, tile_first,
         scatter_fn=scatter_fn, mode=mode,
         e_blk=geom.E_BLK, w=geom.W, t=geom.T, n_out_tiles=n_out_tiles,
-        n_segments=n_segments, interpret=interpret)
+        n_segments=n_segments, interpret=interpret, pipeline="big")

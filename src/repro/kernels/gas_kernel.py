@@ -34,6 +34,13 @@ partial, so the result is the single-grid result bit for bit.
 The same body serves both pipelines; they differ only in what the window
 input *is* (raw vprops windows for Little, compacted unique-source windows
 for Big) — exactly the paper's division of labour.
+
+Each launch carries its pipeline kind as kernel metadata
+(``pipeline``: ``"little"`` or ``"big"``), which the compiled custom call
+keeps as ``frontend_attributes={kernel_metadata={"pipeline":"big"}}``
+and a profiler trace shows in the launch's ``XLA Ops`` event name. The
+instruction keeps the name of its jitted wrapper (``%gas_pallas_call.N``)
+either way; a ``pallas_call(name=...)`` would rename it.
 """
 from __future__ import annotations
 
@@ -184,7 +191,7 @@ def make_gas_kernel(scatter_fn: Callable, mode: str, e_blk: int, w: int,
 
 def _gas_grid(prev, vwin, src_local, dst_local, weights, valid, window_id,
               tile_id, tile_first, *, lo, scatter_fn, mode, e_blk, w, t,
-              interpret):
+              interpret, pipeline):
     """One grid over blocks ``[lo, lo + len(window_id))`` of the full
     operand arrays (the edge index maps add ``lo``; only the small
     prefetch tables are sliced). ``prev`` is aliased to the output."""
@@ -214,6 +221,7 @@ def _gas_grid(prev, vwin, src_local, dst_local, weights, valid, window_id,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        metadata={"pipeline": pipeline} if pipeline else None,
     )(window_id, tile_id, tile_first, vwin, src_local, dst_local, weights,
       valid, prev)
 
@@ -221,12 +229,12 @@ def _gas_grid(prev, vwin, src_local, dst_local, weights, valid, window_id,
 @functools.partial(
     jax.jit,
     static_argnames=("scatter_fn", "mode", "e_blk", "w", "t", "n_out_tiles",
-                     "interpret", "max_grid_blocks"),
+                     "interpret", "max_grid_blocks", "pipeline"),
 )
 def gas_pallas_call(vwin, src_local, dst_local, weights, valid,
                     window_id, tile_id, tile_first, *,
                     scatter_fn, mode, e_blk, w, t, n_out_tiles, interpret,
-                    max_grid_blocks=MAX_GRID_BLOCKS):
+                    max_grid_blocks=MAX_GRID_BLOCKS, pipeline=None):
     """Run the blocked GAS kernel. All shape args static.
 
     vwin:      (n_windows, W) property windows (raw or compacted)
@@ -235,6 +243,8 @@ def gas_pallas_call(vwin, src_local, dst_local, weights, valid,
     window_id, tile_id, tile_first: (n_blocks,) int32 prefetch tables
     interpret: run the kernel in Pallas interpret mode (hosts without a
                TPU) instead of compiling it with Mosaic.
+    pipeline:  "little" or "big", kept as the launch's kernel metadata
+               (module docstring); None leaves it empty.
     returns (n_out_tiles, T) accumulator tiles.
     """
     n_blocks = window_id.shape[0]
@@ -248,19 +258,19 @@ def gas_pallas_call(vwin, src_local, dst_local, weights, valid,
         out = _gas_grid(out, vwin, *edges, window_id[lo:hi],
                         tile_id[lo:hi], tile_first[lo:hi], lo=lo,
                         scatter_fn=scatter_fn, mode=mode, e_blk=e_blk, w=w,
-                        t=t, interpret=interpret)
+                        t=t, interpret=interpret, pipeline=pipeline)
     return out.reshape(n_out_tiles, t)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("scatter_fn", "mode", "e_blk", "w", "t", "n_out_tiles",
-                     "n_segments", "interpret"),
+                     "n_segments", "interpret", "pipeline"),
 )
 def gas_pallas_call_segmented(vwin, src_local, dst_local, weights, valid,
                               window_id, tile_id, tile_first, *,
                               scatter_fn, mode, e_blk, w, t, n_out_tiles,
-                              n_segments, interpret):
+                              n_segments, interpret, pipeline=None):
     """One grid over the concatenation of ``n_segments`` tile-disjoint
     block ranges (a packed lane) — the fused alternative to issuing one
     :func:`gas_pallas_call` per plan entry.
@@ -288,4 +298,4 @@ def gas_pallas_call_segmented(vwin, src_local, dst_local, weights, valid,
         vwin, src_local, dst_local, weights, valid,
         window_id, tile_id, tile_first,
         scatter_fn=scatter_fn, mode=mode, e_blk=e_blk, w=w, t=t,
-        n_out_tiles=n_out_tiles, interpret=interpret)
+        n_out_tiles=n_out_tiles, interpret=interpret, pipeline=pipeline)

@@ -29,7 +29,7 @@ def little_pipeline(vprops_padded, src_local, dst_local, weights, valid,
         window_id, tile_id, tile_first,
         scatter_fn=scatter_fn, mode=mode,
         e_blk=geom.E_BLK, w=geom.W, t=geom.T, n_out_tiles=n_out_tiles,
-        interpret=interpret)
+        interpret=interpret, pipeline="little")
 
 
 def little_pipeline_packed(vprops_padded, src_local, dst_local, weights,
@@ -48,4 +48,4 @@ def little_pipeline_packed(vprops_padded, src_local, dst_local, weights,
         window_id, tile_id, tile_first,
         scatter_fn=scatter_fn, mode=mode,
         e_blk=geom.E_BLK, w=geom.W, t=geom.T, n_out_tiles=n_out_tiles,
-        n_segments=n_segments, interpret=interpret)
+        n_segments=n_segments, interpret=interpret, pipeline="little")

@@ -15,23 +15,25 @@ did THIS request's 900 ms go?". This package adds:
   analytic per-lane byte/FLOP footprints (:class:`LaneFootprint`)
   combined with measured lane times into achieved GB/s, arithmetic
   intensity and %-of-peak (:class:`UtilizationAccumulator`).
-* :mod:`~repro.obs.ledger` — :class:`PerfLedger`, the append-only
-  JSONL perf-regression ledger benchmark runs write and ``run.py
-  compare`` reports on.
+* :mod:`~repro.obs.jitcount` — per-thread and process-wide counts of
+  jaxpr traces, backend compiles and persistent-cache hits/misses
+  from one ``jax.monitoring`` listener, installed on import.
 
 See docs/OBSERVABILITY.md for the span taxonomy and usage.
 """
+from . import jitcount
 from .drift import DriftAccumulator
-from .ledger import PerfLedger, flatten_metrics, git_sha
+from .jitcount import JitCounts
 from .profile import (LaneFootprint, UtilizationAccumulator,
                       jaxpr_lane_bytes, lane_footprint, lane_footprints)
-from .trace import (NOOP_SPAN, Span, SpanContext, Tracer, current,
-                    current_ctx, current_tracer, span)
+from .trace import (Span, SpanContext, Tracer, current, current_ctx,
+                    current_tracer, span)
+
+jitcount.install()
 
 __all__ = [
-    "DriftAccumulator", "LaneFootprint", "NOOP_SPAN", "PerfLedger",
-    "Span", "SpanContext", "Tracer", "UtilizationAccumulator",
-    "current", "current_ctx", "current_tracer", "flatten_metrics",
-    "git_sha", "jaxpr_lane_bytes", "lane_footprint", "lane_footprints",
-    "span",
+    "DriftAccumulator", "JitCounts", "LaneFootprint", "Span",
+    "SpanContext", "Tracer", "UtilizationAccumulator", "current",
+    "current_ctx", "current_tracer", "jaxpr_lane_bytes", "jitcount",
+    "lane_footprint", "lane_footprints", "span",
 ]

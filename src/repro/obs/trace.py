@@ -9,11 +9,21 @@ objects holding them, so an abandoned span costs nothing but its own
 allocation.
 
 Context propagation is thread-local by default: ``with obs.span(...)``
-nests under whatever span the current thread last activated, and costs
-one dict lookup (returning a shared no-op) when no tracer is active —
-library code (store, planner, executor) can be instrumented
-unconditionally. Two boundaries break thread-locality and use explicit
-carriers instead:
+nests under whatever span the current thread last activated. Library
+code (store, planner, executor) is instrumented unconditionally.
+
+One span, two sinks: ``obs.span(name)`` always enters a
+``jax.profiler.TraceAnnotation(name)`` on the calling thread — around
+the recorded :class:`Span` when a tracer is active, as its only effect
+when none is. So every span shows up in a JAX profiler trace, on the
+device trace's clock, with no :class:`Tracer` installed; with the
+profiler off the annotation costs a few microseconds and formats
+nothing (no attributes are passed to it). Spans begun with
+:meth:`Tracer.start_trace` / :meth:`Tracer.start_span` (``job:{app}``,
+``queue.wait``) may end on another thread, which a profiler annotation
+cannot, so they stay in the tracer only.
+
+Two boundaries break thread-locality and use explicit carriers instead:
 
 * the **scheduler queue** hand-off: the submitting thread starts the
   root + queue spans and stores their contexts on the job object; the
@@ -41,9 +51,11 @@ import uuid
 from collections import OrderedDict
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
-    "NOOP_SPAN", "Span", "SpanContext", "Tracer", "current",
-    "current_ctx", "current_tracer", "span",
+    "Span", "SpanContext", "Tracer", "current", "current_ctx",
+    "current_tracer", "span",
 ]
 
 
@@ -136,9 +148,9 @@ class Span:
         }
 
 
-class _NoopSpan:
-    """Inert Span stand-in returned when no tracer is active."""
-    __slots__ = ()
+class _Annotation(TraceAnnotation):
+    """What :func:`span` gives with no tracer active: the profiler
+    annotation alone, with the inert part of the Span surface."""
     ended = True
     context = None
     dur = None
@@ -150,14 +162,6 @@ class _NoopSpan:
     def end(self, t_end=None, **attrs):
         return self
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NOOP_SPAN = _NoopSpan()
 
 _local = threading.local()
 
@@ -177,14 +181,17 @@ def current_ctx() -> Optional[SpanContext]:
 
 class _SpanCM:
     """Context manager: opens a child span of the thread-local context
-    and makes it the thread-local context for the block."""
-    __slots__ = ("_span", "_prev")
+    and makes it the thread-local context for the block, inside a
+    profiler annotation of the same name."""
+    __slots__ = ("_span", "_prev", "_tm")
 
     def __init__(self, sp: Span):
         self._span = sp
         self._prev = None
+        self._tm = TraceAnnotation(sp.name)
 
     def __enter__(self) -> Span:
+        self._tm.__enter__()
         self._prev = getattr(_local, "ctx", None)
         _local.ctx = self._span.context
         return self._span
@@ -194,22 +201,22 @@ class _SpanCM:
         if exc_type is not None and "error" not in self._span.attrs:
             self._span.attrs["error"] = f"{exc_type.__name__}: {exc}"
         self._span.end()
+        self._tm.__exit__(exc_type, exc, tb)
         return False
 
 
 def span(name: str, category: str = "", **attrs: Any):
     """Open a child span of this thread's active context.
 
-    Returns a context manager yielding the :class:`Span` (or a shared
-    no-op when no tracer is active — safe to call unconditionally from
-    library code; the off cost is one attribute lookup).
+    Returns a context manager yielding the :class:`Span`, inside a
+    profiler annotation named ``name``. With no tracer (or no context)
+    active it yields an inert stand-in and only the annotation is
+    entered, so library code calls it unconditionally.
     """
     tracer = getattr(_local, "tracer", None)
-    if tracer is None:
-        return NOOP_SPAN
     ctx = getattr(_local, "ctx", None)
-    if ctx is None:
-        return NOOP_SPAN
+    if tracer is None or ctx is None:
+        return _Annotation(name)
     sp = Span(tracer, name, category, ctx.trace_id, ctx.span_id,
               attrs or None)
     return _SpanCM(sp)

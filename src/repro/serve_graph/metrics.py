@@ -21,7 +21,7 @@ import threading
 from collections import deque
 from typing import Deque, Dict, Optional
 
-from ..obs import DriftAccumulator, UtilizationAccumulator
+from ..obs import DriftAccumulator, UtilizationAccumulator, jitcount
 
 __all__ = ["RequestMetrics", "ServiceMetrics", "merge_expositions"]
 
@@ -94,6 +94,9 @@ class RequestMetrics:
     t_plan_ms: Optional[float] = None     # Planner (cache hit ~ 0)
     t_execute_ms: Optional[float] = None  # Executor materialize + run
     t_total_ms: Optional[float] = None    # submit -> result available
+    # fused iteration programs JAX traced while serving this request
+    # (0 on an executor-cache hit; repro.obs.jitcount, this thread only)
+    iteration_traces: Optional[int] = None
     error: Optional[str] = None
 
     def as_dict(self) -> dict:
@@ -393,6 +396,11 @@ class ServiceMetrics:
         snap["drift"] = self.drift.report()   # its own lock
         snap["utilization"] = self.utilization.report()   # its own lock
         snap["calibration"] = self._calibration_info()
+        jit = jitcount.totals()    # process-wide, its own lock
+        snap["jit"] = {"traces": jit.traces, "compiles": jit.compiles,
+                       "cache_hits": jit.cache_hits,
+                       "cache_misses": jit.cache_misses,
+                       "trace_seconds": jit.trace_s}
         return snap
 
     def snapshot_json(self, **extra) -> str:
@@ -503,6 +511,19 @@ class ServiceMetrics:
                "Deepest delta chain behind any registered snapshot "
                "(replay length of a cold rebuild).",
                [((), snap["max_chain_depth"])])
+        jit = snap["jit"]
+        for name, help_ in (
+                ("traces", "Jaxpr traces in this process (nested jits "
+                 "count; repro.obs.jitcount)."),
+                ("compiles", "XLA backend compile requests in this "
+                 "process, persistent-cache fetches included."),
+                ("cache_hits", "Programs fetched from the persistent "
+                 "compilation cache."),
+                ("cache_misses", "Programs compiled and written to the "
+                 "persistent compilation cache."),
+                ("trace_seconds", "Seconds spent tracing to jaxprs and "
+                 "lowering them to modules.")):
+            metric(f"jit_{name}_total", "counter", help_, [((), jit[name])])
         calib = snap.get("calibration")
         if calib is not None:
             metric("calibration_version", "gauge",

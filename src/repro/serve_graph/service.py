@@ -58,7 +58,7 @@ from .. import obs
 from ..control.pool import WorkerCrashed, WorkerPool
 from ..control.scheduler import (DeadlineExpired, JobScheduler, QueueFull,
                                  QuotaExceeded, RejectedJob, TenantQuota)
-from ..core.executor import Executor
+from ..core.executor import ITERATION_PROGRAM, Executor
 from ..core.gas import BUILTIN_APPS, GASApp
 from ..core.planner import PlanConfig
 from ..core.store import GraphStore
@@ -1297,6 +1297,7 @@ class GraphService:
         # deliberately absent from the executor key (unlike the job key)
         exec_key = (job.skey, job.key[1], job.config.cache_key(), job.path,
                     job.shard)
+        jit0 = obs.jitcount.thread_counts()
         t0 = time.perf_counter()
         with contextlib.ExitStack() as stack:
             # the lease stays held for the whole execution, but the
@@ -1321,25 +1322,27 @@ class GraphService:
                               hit=plan_hit) as sp:
                     bundle = store.plan(job.config)
                 t_plan_ms = (time.perf_counter() - t0) * 1e3
-                if job.shard is not None:
-                    from ..sharding.executor import ShardedExecutor
-                    ex = ShardedExecutor(store, bundle, job.make_app(),
-                                         devices=job.shard, path=job.path)
-                else:
-                    calib = (self._autotuner.calibrator
-                             if self._autotuner is not None else None)
-                    ex = Executor(store, bundle, job.make_app(),
-                                  path=job.path,
-                                  drift_parent=self.metrics.drift,
-                                  util_parent=self.metrics.utilization,
-                                  calibrator=calib)
-                nbytes = ex.memory_footprint()
-                with self._lock:
-                    if exec_key in self._executors:
-                        self._drop_executor(exec_key)   # racing build won
-                    self._executors[exec_key] = (ex, nbytes)
-                    self._executor_bytes += nbytes
-                    self._trim_executors()
+                with obs.span("service.executor", "service"):
+                    if job.shard is not None:
+                        from ..sharding.executor import ShardedExecutor
+                        ex = ShardedExecutor(store, bundle, job.make_app(),
+                                             devices=job.shard,
+                                             path=job.path)
+                    else:
+                        calib = (self._autotuner.calibrator
+                                 if self._autotuner is not None else None)
+                        ex = Executor(store, bundle, job.make_app(),
+                                      path=job.path,
+                                      drift_parent=self.metrics.drift,
+                                      util_parent=self.metrics.utilization,
+                                      calibrator=calib)
+                    nbytes = ex.memory_footprint()
+                    with self._lock:
+                        if exec_key in self._executors:
+                            self._drop_executor(exec_key)  # racing build won
+                        self._executors[exec_key] = (ex, nbytes)
+                        self._executor_bytes += nbytes
+                        self._trim_executors()
 
             t0 = time.perf_counter()
             with obs.span("service.execute", "service", app=job.app_name,
@@ -1347,6 +1350,7 @@ class GraphService:
                 result = ex.run(max_iters=job.max_iters)
                 sp.set(iterations=result[1]["iterations"])
             t_execute_ms = (time.perf_counter() - t0) * 1e3
+        traced = (obs.jitcount.thread_counts() - jit0).traced
 
         self.metrics.record_execution(store_hit, plan_hit)
         self._record_cost(job,
@@ -1354,7 +1358,8 @@ class GraphService:
         self._finish(job, result=result, store_hit=store_hit,
                      plan_hit=plan_hit, t_queue_ms=t_queue_ms,
                      t_store_ms=t_store_ms, t_plan_ms=t_plan_ms,
-                     t_execute_ms=t_execute_ms)
+                     t_execute_ms=t_execute_ms,
+                     iteration_traces=traced[ITERATION_PROGRAM])
         # drift policy check AFTER the handles resolve: a retune sweeps
         # time_lanes + rebuilds plans, and must not delay the request
         # that happened to trip it. Sharded executors have no time_lanes
@@ -1371,7 +1376,7 @@ class GraphService:
 
     def _finish(self, job: _Job, result=None, error=None, store_hit=None,
                 plan_hit=None, t_queue_ms=None, t_store_ms=None,
-                t_plan_ms=None, t_execute_ms=None,
+                t_plan_ms=None, t_execute_ms=None, iteration_traces=None,
                 event: Optional[str] = None) -> None:
         # unlink and snapshot the handle list atomically: a twin either
         # attaches before this (and is resolved below) or finds the job
@@ -1416,6 +1421,7 @@ class GraphService:
                 m.t_store_ms = t_store_ms
                 m.t_plan_ms = t_plan_ms
                 m.t_execute_ms = t_execute_ms
+                m.iteration_traces = iteration_traces
             if error is not None:
                 m.error = "".join(traceback.format_exception_only(
                     type(error), error)).strip()

@@ -1,17 +1,22 @@
 """Observability tests: tracer/span mechanics, carriers across the
-queue and process-pool boundaries, per-lane perf-model drift, and the
-Chrome-trace export through the HTTP job API.
+queue and process-pool boundaries, per-lane perf-model drift, the
+Chrome-trace export through the HTTP job API, spans in a JAX profiler
+trace, and the per-thread compile counts.
 
 Tracer unit tests are pure Python. The integration tests run tiny RMAT
 graphs on the ref path (control-plane suite geometry); the pool test
 pays one spawn startup and is the slowest item here.
 """
+import glob
 import json
+import os
 import threading
 import time
 import urllib.error
 import urllib.request
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -20,7 +25,9 @@ from repro import obs
 from repro.control import ControlPlane
 from repro.core.types import Geometry
 from repro.graphs.rmat import rmat
-from repro.obs import NOOP_SPAN, DriftAccumulator, SpanContext, Tracer
+from repro.core.executor import ITERATION_PROGRAM
+from repro.obs import DriftAccumulator, SpanContext, Tracer, jitcount
+from repro.serve_graph import GraphService
 
 GEOM = Geometry(U=512, W=512, T=512, E_BLK=128, big_batch=2)
 WAIT = 300.0
@@ -38,10 +45,12 @@ def g1():
 class TestTracer:
     def test_span_off_is_noop(self):
         # library code calls obs.span unconditionally; with no tracer
-        # bound to the thread it must return the shared no-op
-        assert obs.span("anything") is NOOP_SPAN
+        # bound to the thread nothing is recorded into any Tracer
+        tr = Tracer()
         with obs.span("anything") as sp:
             sp.set(x=1).end()           # all inert
+            assert sp.context is None and obs.current_ctx() is None
+        assert tr.stats()["spans_recorded"] == 0 and tr.trace_ids() == []
 
     def test_nesting_follows_thread_local_context(self):
         tr = Tracer()
@@ -374,3 +383,203 @@ class TestEndToEndTrace:
         snap = plane.metrics_snapshot()
         assert snap["tracer"]["spans_recorded"] > 0
         assert snap["tracer"]["traces"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock; compile counts per thread
+# ---------------------------------------------------------------------------
+
+SERVED = ("service.execute", "service.executor", "executor.compile",
+          "executor.iteration", "executor.sync", "executor.converged",
+          "executor.readback")
+
+
+def _host_events(tdir, names):
+    """(start_ns, end_ns, name) of the host events named in ``names``
+    in the one profiler trace under ``tdir``."""
+    (path,) = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"),
+                        recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    return sorted((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                  for plane in pd.planes if not plane.name.startswith(
+                      "/device:")
+                  for line in plane.lines for ev in line.events
+                  if ev.name in names)
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+class TestProfilerSpans:
+    def test_span_is_a_profiler_annotation(self, tmp_path):
+        tr = Tracer()
+        root = tr.start_trace("root")
+        with jax.profiler.trace(str(tmp_path)):
+            with obs.span("service.bare"):
+                pass
+            with tr.activate(root.context):
+                with obs.span("service.recorded") as sp:
+                    sp.set(k=1)
+        root.end()
+        names = [e[2] for e in _host_events(
+            str(tmp_path), {"service.bare", "service.recorded"})]
+        assert names == ["service.bare", "service.recorded"]
+        recorded = [d["name"] for d in tr.export(root.trace_id)]
+        assert recorded == ["root", "service.recorded"]
+
+    def test_served_request_spans_without_a_tracer(self, g1, tmp_path):
+        with GraphService(default_geom=GEOM, default_path="ref") as svc:
+            fp = svc.register(g1)
+            with jax.profiler.trace(str(tmp_path)):
+                h = svc.submit(fingerprint=fp, app="bfs",
+                               app_kwargs={"root": 5})
+                _, meta = h.result(WAIT)
+            (ex, _), = svc._executors.values()
+        ev = _host_events(str(tmp_path), set(SERVED))
+        by = {}
+        for e in ev:
+            by.setdefault(e[2], []).append(e)
+        assert set(by) == set(SERVED)
+        (execute,), (build,) = by["service.execute"], by["service.executor"]
+        (comp,), (back,) = by["executor.compile"], by["executor.readback"]
+        its = by["executor.iteration"]
+        assert len(its) == meta["iterations"]
+        assert build[1] <= execute[0]
+        assert all(_inside(e, execute) for e in its + [back])
+        assert _inside(comp, its[0]) and back[0] >= its[-1][1]
+        for name in ("executor.sync", "executor.converged"):
+            assert len(by[name]) == len(its)
+            assert all(_inside(e, it) for e, it in zip(by[name], its))
+        assert comp[1] <= by["executor.sync"][0][0]
+        # the annotations alone do not switch to the per-lane program
+        assert ex._traced_fns is None and ex._iter_fn is not None
+
+    def test_tracer_records_the_same_spans_and_stays_fused(self, g1):
+        tr = Tracer(lane_detail=False)
+        with GraphService(default_geom=GEOM, default_path="ref",
+                          tracer=tr) as svc:
+            h = svc.submit(g1, "bfs", app_kwargs={"root": 6})
+            _, meta = h.result(WAIT)
+            (ex, _), = svc._executors.values()
+        spans = tr.export(h.trace_ctx.trace_id)
+        names = [d["name"] for d in spans]
+        assert set(SERVED) <= set(names)
+        assert names.count("executor.iteration") == meta["iterations"]
+        assert "executor.lane" not in names
+        assert ex._traced_fns is None
+        (comp,) = [d for d in spans if d["name"] == "executor.compile"]
+        assert comp["attrs"]["traces"] >= 1
+        assert comp["attrs"]["compiles"] >= 1
+        assert {"cache_hits", "cache_misses"} <= set(comp["attrs"])
+
+
+class TestJitCounts:
+    def test_counts_are_per_thread(self):
+        def fresh(x):
+            return x * 3 + 1
+
+        out = {}
+
+        def worker():
+            c0 = jitcount.thread_counts()
+            jax.jit(fresh)(jnp.ones(3)).block_until_ready()
+            out["d"] = jitcount.thread_counts() - c0
+
+        mine0, tot0 = jitcount.thread_counts(), jitcount.totals()
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join(60)
+        assert out["d"].traced["fresh"] == 1 and out["d"].compiles >= 1
+        assert out["d"].trace_s > 0
+        assert (jitcount.thread_counts() - mine0).traced["fresh"] == 0
+        assert (jitcount.totals() - tot0).traced["fresh"] == 1
+
+    def test_counts_survive_many_threads(self):
+        """16 threads, each tracing 4 fresh functions with a short
+        switch interval: no count is lost from a thread or the total."""
+        import sys
+        n_threads, per = 16, 4
+        deltas = [None] * n_threads
+
+        def worker(i):
+            c0 = jitcount.thread_counts()
+            for k in range(per):
+                def f(x, k=k):
+                    return x + k
+                f.__name__ = f"stress_{i}"
+                jax.jit(f)(jnp.ones(2)).block_until_ready()
+            deltas[i] = jitcount.thread_counts() - c0
+
+        tot0 = jitcount.totals()
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            ts = [threading.Thread(target=worker, args=(i,))
+                  for i in range(n_threads)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(120)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in ts)
+        names = {f"stress_{i}" for i in range(n_threads)}
+        for i, d in enumerate(deltas):
+            assert {k: v for k, v in d.traced.items() if k in names} == \
+                {f"stress_{i}": per}
+        tot = jitcount.totals() - tot0
+        assert {k: tot.traced[k] for k in names} == dict.fromkeys(names, per)
+
+    def test_iteration_traces_new_root_then_repeat(self, g1):
+        with GraphService(default_geom=GEOM, default_path="ref") as svc:
+            fp = svc.register(g1)
+            got = []
+            for root in (7, 7, 8):
+                h = svc.submit(fingerprint=fp, app="sssp",
+                               app_kwargs={"root": root})
+                h.result(WAIT)
+                got.append(h.metrics.iteration_traces)
+        assert got == [1, 0, 1]
+        assert h.metrics.as_dict()["iteration_traces"] == 1
+
+    def test_iteration_traces_exact_with_two_workers(self, g1):
+        roots = (11, 12, 13, 14)
+        with GraphService(default_geom=GEOM, default_path="ref",
+                          workers=2) as svc:
+            fp = svc.register(g1)
+            for expect in (1, 0):
+                hs = [svc.submit(fingerprint=fp, app="bfs",
+                                 app_kwargs={"root": r}) for r in roots]
+                for h in hs:
+                    h.result(WAIT)
+                assert [h.metrics.iteration_traces for h in hs] == \
+                    [expect] * len(roots)
+
+    def test_traced_under_the_iteration_program_name(self, g1):
+        c = api.compile(g1, "wcc", geom=GEOM, path="ref", n_lanes=2)
+        c0 = jitcount.thread_counts()
+        c.run(max_iters=2)
+        c.run(max_iters=2)
+        assert (jitcount.thread_counts() - c0).traced[ITERATION_PROGRAM] == 1
+
+    def test_metrics_render_jit_families(self, g1):
+        from repro.serve_graph.metrics import merge_expositions
+        with GraphService(default_geom=GEOM, default_path="ref") as svc:
+            svc.submit(g1, "pagerank").result(WAIT)
+            text = svc.metrics.render_prometheus()
+            snap = svc.metrics.snapshot()
+        vals = {}
+        for line in merge_expositions(text).splitlines():
+            if line.startswith("regraph_jit_"):
+                name, v = line.split()
+                vals[name] = float(v)
+        assert set(vals) == {f"regraph_jit_{n}_total" for n in (
+            "traces", "compiles", "cache_hits", "cache_misses",
+            "trace_seconds")}
+        assert vals["regraph_jit_traces_total"] >= 1
+        assert vals["regraph_jit_compiles_total"] >= 1
+        assert vals["regraph_jit_trace_seconds_total"] > 0
+        assert snap["jit"]["traces"] >= vals["regraph_jit_traces_total"]
+        for fam in vals:
+            assert f"# TYPE {fam} counter" in text

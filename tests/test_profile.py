@@ -1,7 +1,6 @@
-"""Utilization profiler + perf ledger + readiness/dashboard tests.
+"""Utilization profiler + readiness/dashboard tests.
 
-Pure-Python pieces (accumulator, ledger, reservoir percentiles, log
-stamps) run with no jax work; the footprint-vs-jaxpr parity and the
+Pure-Python pieces (accumulator, reservoir percentiles, log stamps) run with no jax work; the footprint-vs-jaxpr parity and the
 export path run one tiny RMAT graph on the ref path like the other
 control-plane tests.
 """
@@ -20,7 +19,6 @@ from repro.control.jobs import JobState
 from repro.core import perf_model
 from repro.core.types import Geometry
 from repro.graphs.rmat import rmat
-from repro.obs.ledger import PerfLedger, flatten_metrics, git_sha
 from repro.obs.profile import UtilizationAccumulator
 from repro.serve_graph import GraphService
 from repro.serve_graph.metrics import ServiceMetrics, _Reservoir
@@ -165,72 +163,6 @@ class TestUtilizationAccumulator:
             acc.add("little", 1.0, 1.0, 1.0, lane=lane)
         assert len(acc.report()["lanes"]) \
             == UtilizationAccumulator._MAX_LANES
-
-
-# ---------------------------------------------------------------------------
-# perf ledger
-# ---------------------------------------------------------------------------
-
-class TestPerfLedger:
-    def test_flatten_metrics(self):
-        doc = {"a": 1, "b": {"c": 2.5, "flag": True, "s": "txt"},
-               "xs": [3, {"d": 4}]}
-        flat = flatten_metrics(doc)
-        assert flat == {"a": 1.0, "b.c": 2.5, "xs.0": 3.0, "xs.1.d": 4.0}
-        assert len(flatten_metrics({str(i): i for i in range(500)},
-                                   max_keys=16)) == 16
-
-    def test_append_and_compare_roundtrip(self, tmp_path):
-        led = PerfLedger(str(tmp_path / "ledger.jsonl"))
-        rec = led.append("fused", {"p50_run_s": 1.0, "teps": 10.0},
-                         sha="abc", geom_key="g", spec_version=2)
-        assert rec["bench"] == "fused" and rec["spec_version"] == 2
-        assert led.records("fused")[0]["metrics"]["teps"] == 10.0
-        rep = led.compare()
-        assert rep["benches"]["fused"]["n_prior"] == 0
-        assert rep["regressions"] == 0
-
-    def test_compare_flags_directions(self, tmp_path):
-        led = PerfLedger(str(tmp_path / "l.jsonl"))
-        for sha in ("a", "b", "c"):
-            led.append("x", {"p50_run_s": 1.0, "teps": 10.0}, sha=sha)
-        led.append("x", {"p50_run_s": 2.0, "teps": 20.0}, sha="d")
-        rep = led.compare()
-        flagged = {f["metric"]: f for f in rep["benches"]["x"]["flagged"]}
-        assert flagged["p50_run_s"]["regression"] is True
-        assert flagged["teps"]["regression"] is False      # improvement
-        assert rep["regressions"] == 1 and rep["flagged"] == 2
-        out = led.render_report(rep)
-        assert "[REGRESSION] p50_run_s" in out
-        assert "[improvement] teps" in out
-
-    def test_lower_is_worse_direction(self, tmp_path):
-        led = PerfLedger(str(tmp_path / "l.jsonl"))
-        led.append("x", {"lane_gbps": 10.0}, sha="a")
-        led.append("x", {"lane_gbps": 1.0}, sha="b")
-        rep = led.compare()
-        f = rep["benches"]["x"]["flagged"][0]
-        assert f["direction"] == "lower_is_worse"
-        assert f["regression"] is True
-
-    def test_corrupt_lines_skipped(self, tmp_path):
-        path = tmp_path / "l.jsonl"
-        led = PerfLedger(str(path))
-        led.append("x", {"v": 1.0}, sha="a")
-        with open(path, "a") as f:
-            f.write("{truncated\n\nnot json at all\n")
-        led.append("x", {"v": 2.0}, sha="b")
-        assert len(led.records()) == 2
-        assert led.compare()["benches"]["x"]["checked"] == 1
-
-    def test_missing_file_is_empty(self, tmp_path):
-        led = PerfLedger(str(tmp_path / "absent.jsonl"))
-        assert led.records() == []
-        assert led.compare() == {"benches": {}, "flagged": 0,
-                                 "regressions": 0, "tolerance": 0.25}
-
-    def test_git_sha_never_raises(self):
-        assert isinstance(git_sha(), str) and git_sha()
 
 
 # ---------------------------------------------------------------------------

@@ -8,12 +8,14 @@ fixture, never at import, so every test worker collects the same tests
 and only the worker running this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from bench import spanreduce, tracereduce
 from repro.core.gas import BUILTIN_APPS
 from repro.core.store import GraphStore
 from repro.core.types import Geometry
@@ -74,6 +76,23 @@ def test_packed_lane_kernel_compiles(one_chip, kind, mode):
     compiled = _compile_lane(one_chip, kind, mode, n_blocks=4096,
                              n_windows=64, n_out_tiles=256)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kind", ["little", "big"])
+def test_launches_carry_their_pipeline_kind(one_chip, kind):
+    """Each launch names its pipeline in the kernel metadata, which a
+    profiler trace keeps in the ``XLA Ops`` event name (the instruction
+    text before ``metadata=``), and is still the kernel to the trace
+    reduction."""
+    text = _compile_lane(one_chip, kind, "min", n_blocks=512, n_windows=16,
+                         n_out_tiles=64).as_text()
+    launches = re.findall(r"%gas_pallas_call\S* = .*?(?=, metadata=)", text,
+                          re.S)
+    assert launches
+    for name in launches:
+        assert tracereduce.is_kernel(name)
+        assert tracereduce.op_kind(name) == tracereduce.KERNEL
+        assert spanreduce.kernel_kind(name) == kind
 
 
 def test_r21_lane_block_count_compiles(one_chip):
