@@ -17,6 +17,7 @@ benchmarks and for debugging a single entry).
 """
 from __future__ import annotations
 
+import threading
 import time
 from typing import List, Optional
 
@@ -167,6 +168,7 @@ class Executor:
 
         self.aux = store.aux
         self._iter_fn = None
+        self._build_lock = threading.Lock()   # one trace of _iter_fn
         self._lane_fns = None   # cached per-lane jits for time_lanes
         self._traced_fns = None  # cached (lane fns, merge_apply) pair
 
@@ -335,8 +337,15 @@ class Executor:
             new.block_until_ready()
         return new
 
-    def run(self, max_iters: Optional[int] = None, collect_history=False):
+    def run(self, max_iters: Optional[int] = None, collect_history=False,
+            start: Optional[GASApp] = None):
         """Run to convergence; returns props in ORIGINAL vertex ids.
+
+        ``start`` supplies the initial properties (its ``init``) and
+        ``self.app`` the iteration: it is an app of the same program,
+        differing only in :data:`~.gas.START_KWARGS` (a new bfs root), so
+        one executor serves every start state without a re-trace.
+        Default: ``self.app``.
 
         When a tracer with ``lane_detail`` is active on this thread, the
         iteration switches to the traced per-lane path (extra dispatches
@@ -353,14 +362,9 @@ class Executor:
         tracer = obs.current_tracer()
         lane_detail = (tracer is not None and tracer.lane_detail
                        and obs.current_ctx() is not None)
-        fresh = False
-        if lane_detail:
-            if self._traced_fns is None:
-                self._traced_fns = self._build_traced_fns()
-        elif self._iter_fn is None:
-            self._iter_fn = self._build_iteration()
-            fresh = True
-        vprops = self.init_props()
+        if lane_detail and self._traced_fns is None:
+            self._traced_fns = self._build_traced_fns()
+        vprops = init_props(self.store, start or self.app)
         iters = max_iters or self.app.max_iters
         est_makespan = self._est_iteration
         history = []
@@ -371,11 +375,7 @@ class Executor:
                 if lane_detail:
                     new = self._run_iteration_traced(vprops, it)
                 else:
-                    if fresh:
-                        new = self._first_call(vprops, it)
-                        fresh = False
-                    else:
-                        new = self._iter_fn(vprops, self.aux, it)
+                    new = self._call_iteration(vprops, it)
                     with obs.span("executor.sync", "executor"):
                         new.block_until_ready()
                 self.drift.add("makespan", est_makespan,
@@ -392,14 +392,29 @@ class Executor:
             out = np.asarray(vprops)[self.store.perm]  # back to original ids
         return out, {"iterations": it_done, "history": history}
 
-    def _first_call(self, vprops, it):
+    def _call_iteration(self, vprops, it):
+        """One call of the fused iteration jit. The first call builds it
+        and runs under ``_build_lock``, and the jit is published only
+        after it, so threads sharing this executor trace it once."""
+        fn = self._iter_fn
+        if fn is None:
+            with self._build_lock:
+                fn = self._iter_fn
+                if fn is None:
+                    fn = self._build_iteration()
+                    new = self._first_call(fn, vprops, it)
+                    self._iter_fn = fn
+                    return new
+        return fn(vprops, self.aux, it)
+
+    def _first_call(self, fn, vprops, it):
         """The first call of a freshly built iteration jit, under an
         ``executor.compile`` span: jaxpr trace, lowering and the compile
         or persistent-cache fetch, up to the dispatch's return. The span
         carries what this thread traced and compiled meanwhile."""
         c0 = obs.jitcount.thread_counts()
         with obs.span("executor.compile", "executor") as sp:
-            new = self._iter_fn(vprops, self.aux, it)
+            new = fn(vprops, self.aux, it)
             d = obs.jitcount.thread_counts() - c0
             sp.set(traces=d.traces, compiles=d.compiles,
                    cache_hits=d.cache_hits, cache_misses=d.cache_misses)
@@ -409,10 +424,8 @@ class Executor:
     def time_iteration(self, repeats: int = 5) -> float:
         """Median wall time of one full iteration (all lanes, serialised —
         single host device). Used by benchmarks."""
-        if self._iter_fn is None:
-            self._iter_fn = self._build_iteration()
         vprops = self.init_props()
-        self._iter_fn(vprops, self.aux, 0).block_until_ready()  # warmup
+        self._call_iteration(vprops, 0).block_until_ready()  # warmup
         ts = []
         for _ in range(repeats):
             t0 = time.perf_counter()

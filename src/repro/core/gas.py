@@ -191,3 +191,12 @@ BUILTIN_APPS = {
     "wcc": make_wcc,
     "closeness": make_closeness,
 }
+
+# the factory kwargs that reach only ``init``: they set where a run
+# starts, not the iteration (scatter, apply, converged), so apps that
+# differ only in them run one compiled program
+START_KWARGS = {
+    "bfs": ("root",),
+    "sssp": ("root",),
+    "closeness": ("sources",),
+}

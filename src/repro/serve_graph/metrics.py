@@ -89,6 +89,9 @@ class RequestMetrics:
     coalesced: bool = False           # attached to an in-flight twin job
     store_hit: Optional[bool] = None
     plan_hit: Optional[bool] = None
+    # a cached executor of the app's program ran it (the service's
+    # warm-path LRU); False where one was built for this request
+    executor_hit: Optional[bool] = None
     t_queue_ms: Optional[float] = None    # submit -> worker pickup
     t_store_ms: Optional[float] = None    # GraphStore fetch-or-build
     t_plan_ms: Optional[float] = None     # Planner (cache hit ~ 0)
@@ -146,6 +149,8 @@ class ServiceMetrics:
         self.store_misses = 0
         self.plan_hits = 0
         self.plan_misses = 0
+        self.executor_hits = 0
+        self.executor_misses = 0
         self.store_evictions = 0
         self.executor_evictions = 0
         # streaming delta updates (GraphService.update)
@@ -223,9 +228,14 @@ class ServiceMetrics:
             self.shed_deadline += 1
             self._tenant(tenant)["shed"] += 1
 
-    def record_execution(self, store_hit: bool, plan_hit: bool) -> None:
+    def record_execution(self, store_hit: bool, plan_hit: bool,
+                         executor_hit: bool) -> None:
         with self._lock:
             self.executions += 1
+            if executor_hit:
+                self.executor_hits += 1
+            else:
+                self.executor_misses += 1
             if store_hit:
                 self.store_hits += 1
             else:
@@ -348,6 +358,11 @@ class ServiceMetrics:
         return self.plan_hits / n if n else 0.0
 
     @property
+    def executor_hit_rate(self) -> float:
+        n = self.executor_hits + self.executor_misses
+        return self.executor_hits / n if n else 0.0
+
+    @property
     def queue_depth(self) -> int:
         fn = self._queue_depth_fn
         return int(fn()) if fn is not None else 0
@@ -364,6 +379,8 @@ class ServiceMetrics:
                 "store_misses": self.store_misses,
                 "plan_hits": self.plan_hits,
                 "plan_misses": self.plan_misses,
+                "executor_hits": self.executor_hits,
+                "executor_misses": self.executor_misses,
                 "store_evictions": self.store_evictions,
                 "executor_evictions": self.executor_evictions,
                 "updates": self.updates,
@@ -389,6 +406,7 @@ class ServiceMetrics:
                 snap[f"p99_{s}_ms"] = self._stage[s].percentile(99)
         snap["store_hit_rate"] = self.store_hit_rate
         snap["plan_hit_rate"] = self.plan_hit_rate
+        snap["executor_hit_rate"] = self.executor_hit_rate
         # OUTSIDE the metrics lock: the hook re-enters the service lock,
         # which other paths take BEFORE this one (record_rejected under
         # submit) — pulling it under our lock would invert the order
@@ -440,7 +458,7 @@ class ServiceMetrics:
                "Jobs load-shed after their deadline expired in queue.",
                [((), snap["shed_deadline"])])
         metric("cache_events_total", "counter",
-               "Store/plan cache outcomes and evictions.",
+               "Store/plan/executor cache outcomes and evictions.",
                [((("layer", "store"), ("event", "hit")), snap["store_hits"]),
                 ((("layer", "store"), ("event", "miss")),
                  snap["store_misses"]),
@@ -449,6 +467,10 @@ class ServiceMetrics:
                 ((("layer", "plan"), ("event", "hit")), snap["plan_hits"]),
                 ((("layer", "plan"), ("event", "miss")),
                  snap["plan_misses"]),
+                ((("layer", "executor"), ("event", "hit")),
+                 snap["executor_hits"]),
+                ((("layer", "executor"), ("event", "miss")),
+                 snap["executor_misses"]),
                 ((("layer", "executor"), ("event", "eviction")),
                  snap["executor_evictions"])])
         metric("updates_total", "counter",

@@ -59,7 +59,7 @@ from ..control.pool import WorkerCrashed, WorkerPool
 from ..control.scheduler import (DeadlineExpired, JobScheduler, QueueFull,
                                  QuotaExceeded, RejectedJob, TenantQuota)
 from ..core.executor import ITERATION_PROGRAM, Executor
-from ..core.gas import BUILTIN_APPS, GASApp
+from ..core.gas import BUILTIN_APPS, START_KWARGS, GASApp
 from ..core.planner import PlanConfig
 from ..core.store import GraphStore
 from ..core.types import Geometry
@@ -189,17 +189,19 @@ class RequestHandle:
 class _Job:
     """One unit of execution: a coalescing group of identical requests."""
 
-    __slots__ = ("key", "skey", "graph", "app_name", "make_app", "config",
-                 "use_dbg", "geom", "max_iters", "path", "shard", "handles",
-                 "t_submit", "tenant", "priority", "model_est", "observers",
-                 "trace_ctx", "root_span", "queue_span")
+    __slots__ = ("key", "exec_key", "skey", "graph", "app_name",
+                 "make_app", "config", "use_dbg", "geom", "max_iters",
+                 "path", "shard", "handles", "t_submit", "tenant",
+                 "priority", "model_est", "observers", "trace_ctx",
+                 "root_span", "queue_span")
 
-    def __init__(self, key, skey: StoreKey, graph: Optional[Graph],
-                 app_name: str, make_app, config: PlanConfig,
-                 geom: Geometry, use_dbg: bool,
+    def __init__(self, key, exec_key, skey: StoreKey,
+                 graph: Optional[Graph], app_name: str, make_app,
+                 config: PlanConfig, geom: Geometry, use_dbg: bool,
                  max_iters: Optional[int], path: Optional[str],
                  shard=None, tenant: str = "default", priority: int = 0):
         self.key = key
+        self.exec_key = exec_key
         self.skey = skey
         self.graph = graph
         self.app_name = app_name
@@ -242,13 +244,16 @@ class GraphService:
     max_plans_per_store: bound of each store's plan LRU.
     max_executors: bound of the warm-path Executor LRU. Store and plan
         caches make re-PLANNING cheap, but a fresh Executor re-traces
-        the jit'd iteration on every request; caching executors keyed
-        like coalescing keys (store, app, config, path, shard) lets
-        warm repeats reuse the compiled function (each shard variant of
-        an otherwise-identical request is its own entry). Executors of
-        an evicted
-        store are purged with it (they would otherwise keep its device
-        arrays alive behind the byte budget's back).
+        the jit'd iteration; executors are keyed on (store, app
+        program, config, path, shard), where the app program is the
+        builtin app and its kwargs less the start-state ones
+        (:data:`~repro.core.gas.START_KWARGS`), so a bfs from a new root
+        reuses the compiled iteration and only its start state is new.
+        The coalescing key keeps every kwarg: requests with different
+        roots share an executor but never a result. Each shard variant
+        is its own entry. Executors of an evicted store are purged with
+        it (they would otherwise keep its device arrays alive behind
+        the byte budget's back).
     executor_byte_budget: optional device-byte bound on the same LRU,
         using each Executor's ``memory_footprint()`` (the bundle's
         materialized/packed payload bytes). Executors sharing a plan
@@ -1033,7 +1038,8 @@ class GraphService:
         fp = resolve_fingerprint(graph, fingerprint)
         skey = store_key(fp, geom, use_dbg)
 
-        app_name, app_token, make_app = _normalize_app(app, app_kwargs)
+        app_name, app_token, program, make_app = _normalize_app(
+            app, app_kwargs)
         if graph_obj is None:
             # NOTE: no auto-registration on the Graph path — only
             # register() pins graphs on the service, so serving many
@@ -1054,6 +1060,9 @@ class GraphService:
 
         job_key = (skey, app_token, config.cache_key(), max_iters, path,
                    shard)
+        # max_iters is a run() argument, not executor state, so it is
+        # deliberately absent from the executor key (unlike the job key)
+        exec_key = (skey, program, config.cache_key(), path, shard)
         # cost estimation reads the store/plan caches (their own locks;
         # the eviction hook re-enters the service lock, so peeking from
         # under it would invert the order) — do it before locking
@@ -1088,9 +1097,10 @@ class GraphService:
                     job.priority = priority
                     self._scheduler.reprioritize(job, priority)
             else:
-                job = _Job(job_key, skey, graph_obj, app_name, make_app,
-                           config, geom, use_dbg, max_iters, path,
-                           shard=shard, tenant=tenant, priority=priority)
+                job = _Job(job_key, exec_key, skey, graph_obj, app_name,
+                           make_app, config, geom, use_dbg, max_iters,
+                           path, shard=shard, tenant=tenant,
+                           priority=priority)
                 job.model_est = model_est
                 job.handles.append(handle)
                 handle._job = job
@@ -1293,10 +1303,6 @@ class GraphService:
             return self._build_store(g, job.geom, job.use_dbg,
                                      fp=job.skey[0])
 
-        # max_iters is a run() argument, not executor state, so it is
-        # deliberately absent from the executor key (unlike the job key)
-        exec_key = (job.skey, job.key[1], job.config.cache_key(), job.path,
-                    job.shard)
         jit0 = obs.jitcount.thread_counts()
         t0 = time.perf_counter()
         with contextlib.ExitStack() as stack:
@@ -1309,11 +1315,13 @@ class GraphService:
                 sp.set(hit=store_hit)
             t_store_ms = (time.perf_counter() - t0) * 1e3
 
+            app = job.make_app()    # this request's start state
             with self._lock:
-                hit = self._executors.get(exec_key)
+                hit = self._executors.get(job.exec_key)
                 if hit is not None:
-                    self._executors.move_to_end(exec_key)
-            if hit is not None:
+                    self._executors.move_to_end(job.exec_key)
+            executor_hit = hit is not None
+            if executor_hit:
                 ex, plan_hit, t_plan_ms = hit[0], True, 0.0
             else:
                 plan_hit = store.has_plan(job.config)
@@ -1325,39 +1333,40 @@ class GraphService:
                 with obs.span("service.executor", "service"):
                     if job.shard is not None:
                         from ..sharding.executor import ShardedExecutor
-                        ex = ShardedExecutor(store, bundle, job.make_app(),
+                        ex = ShardedExecutor(store, bundle, app,
                                              devices=job.shard,
                                              path=job.path)
                     else:
                         calib = (self._autotuner.calibrator
                                  if self._autotuner is not None else None)
-                        ex = Executor(store, bundle, job.make_app(),
-                                      path=job.path,
+                        ex = Executor(store, bundle, app, path=job.path,
                                       drift_parent=self.metrics.drift,
                                       util_parent=self.metrics.utilization,
                                       calibrator=calib)
                     nbytes = ex.memory_footprint()
                     with self._lock:
-                        if exec_key in self._executors:
-                            self._drop_executor(exec_key)  # racing build won
-                        self._executors[exec_key] = (ex, nbytes)
+                        if job.exec_key in self._executors:
+                            # racing build won
+                            self._drop_executor(job.exec_key)
+                        self._executors[job.exec_key] = (ex, nbytes)
                         self._executor_bytes += nbytes
                         self._trim_executors()
 
             t0 = time.perf_counter()
             with obs.span("service.execute", "service", app=job.app_name,
-                          executor_hit=hit is not None) as sp:
-                result = ex.run(max_iters=job.max_iters)
+                          executor_hit=executor_hit) as sp:
+                result = ex.run(max_iters=job.max_iters, start=app)
                 sp.set(iterations=result[1]["iterations"])
             t_execute_ms = (time.perf_counter() - t0) * 1e3
         traced = (obs.jitcount.thread_counts() - jit0).traced
 
-        self.metrics.record_execution(store_hit, plan_hit)
+        self.metrics.record_execution(store_hit, plan_hit, executor_hit)
         self._record_cost(job,
                           (t_store_ms + t_plan_ms + t_execute_ms) / 1e3)
         self._finish(job, result=result, store_hit=store_hit,
-                     plan_hit=plan_hit, t_queue_ms=t_queue_ms,
-                     t_store_ms=t_store_ms, t_plan_ms=t_plan_ms,
+                     plan_hit=plan_hit, executor_hit=executor_hit,
+                     t_queue_ms=t_queue_ms, t_store_ms=t_store_ms,
+                     t_plan_ms=t_plan_ms,
                      t_execute_ms=t_execute_ms,
                      iteration_traces=traced[ITERATION_PROGRAM])
         # drift policy check AFTER the handles resolve: a retune sweeps
@@ -1375,8 +1384,9 @@ class GraphService:
                     {"error": repr(e), "applied": False})
 
     def _finish(self, job: _Job, result=None, error=None, store_hit=None,
-                plan_hit=None, t_queue_ms=None, t_store_ms=None,
-                t_plan_ms=None, t_execute_ms=None, iteration_traces=None,
+                plan_hit=None, executor_hit=None, t_queue_ms=None,
+                t_store_ms=None, t_plan_ms=None, t_execute_ms=None,
+                iteration_traces=None,
                 event: Optional[str] = None) -> None:
         # unlink and snapshot the handle list atomically: a twin either
         # attaches before this (and is resolved below) or finds the job
@@ -1411,6 +1421,7 @@ class GraphService:
             m = h.metrics
             m.store_hit = store_hit
             m.plan_hit = plan_hit
+            m.executor_hit = executor_hit
             # each handle gets ITS OWN end-to-end latency; the stage
             # breakdown describes the one execution, so it lands only on
             # the request that triggered it — coalesced twins keep the
@@ -1476,7 +1487,7 @@ class GraphService:
                 g = g.materialize()
             return self._build_store(g, geom, use_dbg, fp=fp)
 
-        _, _, make_app = _normalize_app(app, None)
+        make_app = _normalize_app(app, None)[3]
         with self.cache.lease(skey, builder) as (store, _hit):
             bundle = store.plan(config)
             ex = Executor(store, bundle, make_app(),
@@ -1515,11 +1526,13 @@ class GraphService:
 
 def _normalize_app(app: Union[GASApp, str],
                    app_kwargs: Optional[dict]
-                   ) -> Tuple[str, tuple, "callable"]:
-    """Return (display name, coalescing token, zero-arg factory).
+                   ) -> Tuple[str, tuple, tuple, "callable"]:
+    """Return (display name, coalescing token, program token, zero-arg
+    factory).
 
-    Builtin apps submitted by name coalesce on (name, kwargs); a
-    prebuilt GASApp instance coalesces only with itself (its parameters
+    Builtin apps submitted by name coalesce on (name, kwargs) and share
+    an executor on (name, kwargs less ``START_KWARGS[name]``); a
+    prebuilt GASApp instance does both only with itself (its parameters
     live in closures the service can't inspect, and GASApp instances
     are stateless across runs, so sharing the instance is safe).
     """
@@ -1528,12 +1541,16 @@ def _normalize_app(app: Union[GASApp, str],
             raise ValueError(f"unknown builtin app {app!r}; available: "
                              f"{sorted(BUILTIN_APPS)}")
         kwargs = dict(app_kwargs or {})
-        token = ("builtin", app,
-                 tuple((k, _hashable(v)) for k, v in sorted(kwargs.items())))
-        return app, token, lambda: BUILTIN_APPS[app](**kwargs)
+        items = tuple((k, _hashable(v)) for k, v in sorted(kwargs.items()))
+        start = START_KWARGS.get(app, ())
+        token = ("builtin", app, items)
+        program = ("builtin", app,
+                   tuple(kv for kv in items if kv[0] not in start))
+        return app, token, program, lambda: BUILTIN_APPS[app](**kwargs)
     if app_kwargs:
         raise ValueError("app_kwargs only apply to builtin app names")
-    return app.name, ("instance", id(app)), lambda: app
+    token = ("instance", id(app))
+    return app.name, token, token, lambda: app
 
 
 def _hashable(v):

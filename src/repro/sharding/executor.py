@@ -240,8 +240,8 @@ class ShardedExecutor:
             arrays = [ops.device_arrays(p) for p in payloads]
             return lambda vprops: fn(vprops, arrays)
 
-        self._dev_fns = [make_dev_fn(ps) if ps else None
-                         for ps in self._dev_payloads]
+        dev_fns = [make_dev_fn(ps) if ps else None
+                   for ps in self._dev_payloads]
 
         def merge_apply(outs, vprops, aux, it):
             accum = jnp.full((V_pad,), ident, dt)
@@ -249,6 +249,8 @@ class ShardedExecutor:
             return app.apply(accum, vprops, aux, it)
 
         self._merge_apply = jax.jit(merge_apply)
+        # published last: a thread that sees _dev_fns sees merge_apply
+        self._dev_fns = dev_fns
 
     def _iterate(self, vprops, it):
         """One sharded iteration: broadcast vprops → per-device local
@@ -266,12 +268,14 @@ class ShardedExecutor:
     def init_props(self):
         return init_props(self.store, self.app)
 
-    def run(self, max_iters: Optional[int] = None, collect_history=False):
+    def run(self, max_iters: Optional[int] = None, collect_history=False,
+            start: Optional[GASApp] = None):
         """Run to convergence; returns ``(props, meta)`` with props in
-        ORIGINAL vertex ids — the same contract as ``Executor.run``."""
+        ORIGINAL vertex ids — the same contract as ``Executor.run``,
+        ``start`` included."""
         if self._dev_fns is None:
             self._build()
-        vprops = self.init_props()
+        vprops = init_props(self.store, start or self.app)
         iters = max_iters or self.app.max_iters
         history = []
         it_done = 0

@@ -540,21 +540,32 @@ class TestJitCounts:
                                app_kwargs={"root": root})
                 h.result(WAIT)
                 got.append(h.metrics.iteration_traces)
-        assert got == [1, 0, 1]
-        assert h.metrics.as_dict()["iteration_traces"] == 1
+        # a new root is a new start state of the same program: only the
+        # first request traces the iteration
+        assert got == [1, 0, 0]
+        assert h.metrics.as_dict()["iteration_traces"] == 0
 
     def test_iteration_traces_exact_with_two_workers(self, g1):
+        """Four roots of one program on two workers: the first round
+        traces once per executor built (both workers may miss the cache
+        at once, and each executor traces once however many threads
+        share it), the second round not at all."""
         roots = (11, 12, 13, 14)
         with GraphService(default_geom=GEOM, default_path="ref",
                           workers=2) as svc:
             fp = svc.register(g1)
-            for expect in (1, 0):
+            rounds = []
+            for _ in range(2):
                 hs = [svc.submit(fingerprint=fp, app="bfs",
                                  app_kwargs={"root": r}) for r in roots]
                 for h in hs:
                     h.result(WAIT)
-                assert [h.metrics.iteration_traces for h in hs] == \
-                    [expect] * len(roots)
+                rounds.append([h.metrics.iteration_traces for h in hs])
+            built = svc.metrics.executor_misses
+        first, second = rounds
+        assert set(first) <= {0, 1} and sum(first) == built
+        assert 1 <= built <= 2
+        assert second == [0] * len(roots)
 
     def test_traced_under_the_iteration_program_name(self, g1):
         c = api.compile(g1, "wcc", geom=GEOM, path="ref", n_lanes=2)

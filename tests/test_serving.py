@@ -6,6 +6,8 @@ Every blocking wait uses an explicit timeout so a queue/worker bug
 fails loudly instead of hanging the suite (CI adds pytest-timeout on
 top as a backstop).
 """
+import inspect
+import sys
 import threading
 
 import numpy as np
@@ -507,3 +509,127 @@ def test_concurrent_submitters_thread_safety(graphs):
             vals = [p for g, p in results.values() if g == gi]
             for v in vals[1:]:
                 np.testing.assert_array_equal(v, vals[0])
+
+
+# ------------------------------------- one executor per app program
+def _fresh(g, app, kw):
+    """The answer of a freshly built executor for one request."""
+    return api.compile(g, gas.BUILTIN_APPS[app](**kw), geom=GEOM,
+                       path="ref", n_lanes=2).run()[0]
+
+
+START_CASES = {
+    "bfs": [{"root": 7}, {"root": 8}, {"root": 7}],
+    "sssp": [{"root": 7}, {"root": 8}],
+    "closeness": [{"sources": np.arange(4)},
+                  {"sources": np.arange(40, 72)}],
+}
+
+
+@pytest.mark.parametrize("app", sorted(START_CASES))
+def test_new_start_state_reuses_the_executor(graphs, app):
+    """A new root or source set runs on the cached executor of the app's
+    program, and each answer equals a fresh executor's for that
+    request: no start state leaks from an earlier request."""
+    g, kws = graphs[0], START_CASES[app]
+    with make_service(workers=1) as svc:
+        hs, got = [], []
+        for kw in kws:
+            hs.append(svc.submit(g, app, app_kwargs=kw, n_lanes=2))
+            got.append(hs[-1].result(timeout=WAIT)[0])
+            np.testing.assert_array_equal(got[-1], _fresh(g, app, kw))
+        assert not np.array_equal(got[0], got[1])
+        assert svc.stats()["cached_executors"] == 1
+        assert [h.metrics.executor_hit for h in hs] == \
+            [False] + [True] * (len(kws) - 1)
+        snap = svc.stats()["service"]
+        assert (snap["executor_misses"], snap["executor_hits"]) == \
+            (1, len(kws) - 1)
+        assert snap["executor_hit_rate"] == (len(kws) - 1) / len(kws)
+        text = svc.metrics.render_prometheus()
+        for event, n in (("hit", len(kws) - 1), ("miss", 1)):
+            assert (f'regraph_cache_events_total{{layer="executor",'
+                    f'event="{event}"}} {n}') in text.splitlines()
+        assert svc.metrics.executions == len(kws)
+
+
+@pytest.mark.parametrize("app,kws", [
+    ("pagerank", [{"damping": 0.85}, {"damping": 0.5}]),
+    ("bfs", [{"root": 3, "max_iters": 2}, {"root": 3, "max_iters": 64}]),
+], ids=["pagerank-damping", "bfs-max_iters"])
+def test_program_kwargs_keep_separate_executors(graphs, app, kws):
+    g = graphs[0]
+    with make_service(workers=1) as svc:
+        hs = [svc.submit(g, app, app_kwargs=kw, n_lanes=2) for kw in kws]
+        for h, kw in zip(hs, kws):
+            np.testing.assert_array_equal(h.result(timeout=WAIT)[0],
+                                          _fresh(g, app, kw))
+        assert svc.stats()["cached_executors"] == 2
+        assert [h.metrics.executor_hit for h in hs] == [False, False]
+
+
+def test_concurrent_roots_share_an_executor_but_never_coalesce(graphs):
+    """Twelve workers (more than a typical test host's cores) and a
+    short switch interval: requests with different roots run at once on
+    shared executors, never coalesce, and each gets its own root's
+    answer."""
+    g, roots = graphs[0], range(1, 13)
+    refs = [_fresh(g, "bfs", {"root": r}) for r in roots]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with make_service(workers=len(roots)) as svc:
+            hs = [svc.submit(g, "bfs", app_kwargs={"root": r}, n_lanes=2)
+                  for r in roots]
+            got = [h.result(timeout=WAIT)[0] for h in hs]
+            stats = svc.stats()
+    finally:
+        sys.setswitchinterval(old)
+    for props, ref in zip(got, refs):
+        np.testing.assert_array_equal(props, ref)
+    snap = stats["service"]
+    assert snap["coalesced"] == 0 and snap["executions"] == len(roots)
+    assert not any(h.metrics.coalesced for h in hs)
+    assert stats["cached_executors"] == 1
+    assert 1 <= snap["executor_misses"] <= len(roots)
+
+
+START_VALUES = {"root": (0, 5),
+                "sources": (np.arange(32), np.arange(7, 39))}
+
+
+@pytest.mark.parametrize("app,kwarg", [
+    (app, kw) for app, kws in sorted(gas.START_KWARGS.items())
+    for kw in kws])
+def test_start_kwargs_reach_only_init(app, kwarg):
+    """The table's claim, checked per app and kwarg: two apps that
+    differ in it compute the same scatter, apply and converged on the
+    same inputs, and a different init."""
+    factory = gas.BUILTIN_APPS[app]
+    assert kwarg in inspect.signature(factory).parameters
+    lo, hi = START_VALUES[kwarg]
+    a, b = factory(**{kwarg: lo}), factory(**{kwarg: hi})
+    rng = np.random.default_rng(0)
+    n = 256
+    if a.gather == "or":
+        src = rng.integers(-2**31, 2**31 - 1, n, dtype=np.int32)
+        prop = rng.integers(-2**31, 2**31 - 1, n, dtype=np.int32)
+    else:
+        src = np.where(rng.random(n) < 0.3, gas.INF,
+                       rng.random(n) * 9).astype(np.float32)
+        prop = np.where(rng.random(n) < 0.5, gas.INF,
+                        rng.random(n) * 9).astype(np.float32)
+    w = (rng.random(n) + 1).astype(np.float32)
+    aux = {"outdeg": rng.integers(0, 5, n).astype(np.float32),
+           "num_v": n, "num_v_pad": n}
+    np.testing.assert_array_equal(a.scatter(src, w), b.scatter(src, w))
+    accum = a.scatter(src, w)
+    for it in (0, 3):
+        np.testing.assert_array_equal(a.apply(accum, prop, aux, it),
+                                      b.apply(accum, prop, aux, it))
+        new = a.apply(accum, prop, aux, it)
+        for old in (prop, new):
+            assert a.converged(old, new, it) == b.converged(old, new, it)
+    assert (a.gather, a.prop_dtype, a.max_iters, a.needs_weights) == \
+        (b.gather, b.prop_dtype, b.max_iters, b.needs_weights)
+    assert not np.array_equal(a.init(aux), b.init(aux))

@@ -214,6 +214,25 @@ def test_shard_memoized_and_counted(shard_store):
     assert shard_store.memory_footprint()["plan_bytes"] >= sh1.nbytes()
 
 
+def test_sharded_run_start_matches_fresh(shard_store):
+    """One ShardedExecutor serves two roots through ``run(start=...)``;
+    each answer equals a freshly built executor's for that root."""
+    cfg = api.PlanConfig(n_lanes=4)
+    shared = api.compile(None, gas.make_bfs(root=0), store=shard_store,
+                         config=cfg, path="ref", shard=1).executor
+    got = []
+    for root in (5, 9):
+        props, meta = shared.run(start=gas.make_bfs(root=root))
+        fresh = api.compile(None, gas.make_bfs(root=root),
+                            store=shard_store, config=cfg, path="ref",
+                            shard=1)
+        ref, ref_meta = fresh.run()
+        np.testing.assert_array_equal(props, ref)
+        assert meta["iterations"] == ref_meta["iterations"]
+        got.append(props)
+    assert not np.array_equal(got[0], got[1])
+
+
 def test_merge_program_is_single_scatter(shard_store):
     """Program-derived gate: the traced merge+apply program contains
     exactly one scatter op — the single cross-device merge."""
