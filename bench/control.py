@@ -6,14 +6,12 @@
 For each seed it makes the cell's graph and the first ``--requests``
 requests of the window's stream (at most ``check_per_app`` of an app, as
 a run compares; only ``--apps`` where given), computes each answer in
-bfloat16 (``reference.control_answer``) and reads it as a run reads a
-served answer (``compare.reading``). It prints one JSON line per seed
+bfloat16 (the ``control`` of the app's file) and reads it as a run reads
+a served answer (``compare.reading``). It prints one JSON line per seed
 with the numbers and ``compare.verdict`` of them, which has to read
 ``correct`` false, and a last line with the smallest reading of each
 number over the seeds: the upper readings the limits are set below.
-The benchmark's own runs never run this. Pagerank runs for the
-iterations the float64 reference takes under the app's stopping rule;
-wcc starts from the vertex ids.
+The benchmark's own runs never run this.
 """
 from __future__ import annotations
 
@@ -37,10 +35,8 @@ def control_readings(bench: Bench, workload: str, seed: int, n_requests: int,
     wl = bench.workload(workload)
     cfg = bench.config(wl["config"])
     mix = bench.traffic(wl["traffic"])
-    src, dst, w = graphgen.edges(cfg["generator"], cfg["scale"],
-                                 cfg["edge_factor"], cfg["structure_seed"],
-                                 seed)
-    g = reference.Graph(1 << cfg["scale"], src, dst, w)
+    n, src, dst, w = graphgen.edges(cfg, seed, bench.root)
+    g = reference.Graph(n, src, dst, w)
     per_app = mix.get("check_per_app", {})
     seen: dict = {}
     cache: dict = {}
@@ -51,11 +47,10 @@ def control_readings(bench: Bench, workload: str, seed: int, n_requests: int,
                 or seen.get(app, 0) >= per_app.get(app, n_requests):
             continue
         seen[app] = seen.get(app, 0) + 1
-        iters = (reference.pagerank(g, kwargs.get("damping", 0.85))[1]
-                 if app == "pagerank" else 0)
-        low = reference.control_answer(g, app, kwargs, iters, dtype)
-        readings.append((app, compare.reading(g, app, kwargs, low, cache)))
-    return compare.worst(readings)
+        low = compare.app(app, bench.root).control(g, kwargs, dtype)
+        readings.append((app, compare.reading(g, app, kwargs, low, cache,
+                                              bench.root)))
+    return compare.worst(readings, bench.root)
 
 
 def main(argv=None) -> int:
@@ -76,12 +71,12 @@ def main(argv=None) -> int:
                                   apps=apps)
         for k, v in values.items():
             lowest[k] = min(lowest.get(k, v), v)
-        correct, checks = compare.verdict(values, 0)
+        correct, checks = compare.verdict(values, 0, bench.root)
         print(json.dumps({"seed": seed, "correct": correct, "checks": checks,
                           "seconds": time.perf_counter() - t0,
                           "device": dev.device_kind}), flush=True)
     print(json.dumps({"workload": args.workload, "control_lowest": lowest,
-                      "limits": compare.LIMITS}), flush=True)
+                      "limits": compare.limits(bench.root)}), flush=True)
     return 0
 
 

@@ -1,9 +1,10 @@
-"""Plain references of the five apps (numpy / scipy), and their control.
+"""Plain references of the apps (numpy / scipy), and their control.
 
 Nothing here imports the program or takes anything it made. Each
 function takes the benchmark's own graph arrays and the request's
 parameters, and returns what the served answer should be, in original
-vertex ids:
+vertex ids. Each app's file (``bench/apps/<app>.py``) says which of
+them is its reference and its control:
 
 * pagerank: pull power iteration on rank / out-degree, float64, stopped
   by the app's own rule (no rank / out-degree moves by 1e-7 or more, at
@@ -17,9 +18,9 @@ vertex ids:
   served labels are from that fixpoint under every numbering at once;
 * closeness: bit b of vertex v set iff v is reachable from sources[b].
 
-The control (``control_answer``) is the same semantics computed in
-bfloat16 with ``jax.numpy``, the nearest precision below the float32
-that the configurations state.
+The control (``control_pagerank``, ``control_fixpoint``) is the same
+semantics computed in bfloat16 with ``jax.numpy``, the nearest precision
+below the float32 that the configurations state.
 """
 from __future__ import annotations
 
@@ -144,64 +145,60 @@ def reach_bits(g: Graph, sources):
     return bits.view(np.int32)
 
 
-def answer(g: Graph, app: str, kwargs: dict):
-    """The reference answer of one request (wcc has none: its labels
-    depend on the store's numbering; see ``wcc_violations``)."""
-    if app == "pagerank":
-        return pagerank(g, kwargs.get("damping", 0.85))[0]
-    if app == "bfs":
-        return bfs(g, kwargs["root"])
-    if app == "sssp":
-        return sssp(g, kwargs["root"])
-    if app == "closeness":
-        return reach_bits(g, kwargs["sources"])
-    raise ValueError(f"no reference answer for app {app!r}")
-
-
 # --------------------------------------------------------------------------
-# control: the same semantics in bfloat16 (jax.numpy, on the default device)
+# control: the same semantics in a lower precision (jax.numpy, on the
+# default device)
 # --------------------------------------------------------------------------
 
-def control_answer(g: Graph, app: str, kwargs: dict, iterations: int,
-                   dtype="bfloat16"):
-    """The reference of one request computed in ``dtype``: pagerank for
-    ``iterations`` (those the float64 reference takes), the min apps to
-    their fixpoint, wcc from the vertex ids. Closeness has no floating
-    point and is computed exactly."""
+def control_pagerank(g: Graph, damping: float, iterations: int,
+                     dtype="bfloat16"):
+    """Pagerank's rank / out-degree computed in ``dtype`` for
+    ``iterations``, as float64."""
     import jax
     import jax.numpy as jnp
 
-    if app == "closeness":
-        return reach_bits(g, kwargs["sources"])
     dt = jnp.dtype(dtype)
     n = g.n
     src, dst = jnp.asarray(g.src), jnp.asarray(g.dst)
-    if app == "pagerank":
-        d = kwargs.get("damping", 0.85)
-        outdeg = jnp.maximum(jnp.bincount(src, length=n), 1).astype(dt)
+    outdeg = jnp.maximum(jnp.bincount(src, length=n), 1).astype(dt)
 
-        @jax.jit
-        def step(p):
-            s = jax.ops.segment_sum(p[src], dst, num_segments=n)
-            return ((1 - d) / n + d * s).astype(dt) / outdeg
+    @jax.jit
+    def step(p):
+        s = jax.ops.segment_sum(p[src], dst, num_segments=n)
+        return ((1 - damping) / n + damping * s).astype(dt) / outdeg
 
-        p = (jnp.full((n,), 1.0 / n, dt) / outdeg).astype(dt)
-        for _ in range(iterations):
-            p = step(p)
-        return np.asarray(p.astype(jnp.float32), np.float64)
+    p = (jnp.full((n,), 1.0 / n, dt) / outdeg).astype(dt)
+    for _ in range(iterations):
+        p = step(p)
+    return np.asarray(p.astype(jnp.float32), np.float64)
 
-    w = jnp.asarray(g.w).astype(dt) if app == "sssp" else None
+
+def control_fixpoint(g: Graph, root=None, *, weighted=False, level=False,
+                     dtype="bfloat16"):
+    """The min-gather fixpoint computed in ``dtype``: from ``root`` at 0
+    (the others at infinity) or, without a root, from the vertex ids;
+    each step takes the min over in-edges of the source's value, plus
+    the edge's weight where ``weighted``; with ``level`` an unreached
+    vertex takes that min plus 1 (bfs), else the min of both (sssp,
+    wcc). Unreached vertices read ``INF``."""
+    import jax
+    import jax.numpy as jnp
+
+    dt = jnp.dtype(dtype)
+    n = g.n
+    src, dst = jnp.asarray(g.src), jnp.asarray(g.dst)
+    w = jnp.asarray(g.w).astype(dt) if weighted else None
     inf = jnp.asarray(jnp.inf, dt)
-    if app == "wcc":
+    if root is None:
         p = jnp.arange(n).astype(dt)
     else:
-        p = jnp.full((n,), inf, dt).at[kwargs["root"]].set(0)
+        p = jnp.full((n,), inf, dt).at[root].set(0)
 
     @jax.jit
     def relax(p):
         x = p[src] if w is None else p[src] + w
         m = jax.ops.segment_min(x, dst, num_segments=n)
-        if app == "bfs":
+        if level:
             return jnp.where((p == inf) & (m < inf), m + 1, p)
         return jnp.minimum(p, m)
 

@@ -5,7 +5,8 @@
 In one process, in this order:
 
 1. find the TPU (fail without one, or with fewer chips than the cell asks);
-2. make the cell's graph from ``--seed`` (``bench/graphgen.py``);
+2. make the cell's graph from ``--seed`` (``bench/graphgen.py`` and
+   the configuration's family file under ``bench/generators/``);
 3. register it with a default ``GraphService`` (1 worker, no pool);
 4. plan it with the model-guided ``PlanConfig()`` and upload the payloads;
 5. check that the executors run compiled Pallas (path ``"pallas"``, the
@@ -16,7 +17,8 @@ In one process, in this order:
    ``--trace 1`` under the profiler, each request inside a
    ``TraceAnnotation``);
 8. compare a seeded sample of the window's answers with the plain
-   references (``bench/reference.py``, ``bench/compare.py``);
+   references (each app's file under ``bench/apps/``,
+   ``bench/compare.py``);
 9. print the result as the last line of standard output.
 
 Set-up phases go to standard error as they finish. The numbers compared,
@@ -73,6 +75,8 @@ class Request:
     t_execute_ms: Optional[float] = None
     answer: Optional[np.ndarray] = None
     error: Optional[str] = None
+    iteration_traces: Optional[int] = None  # RequestMetrics, where present
+    executor_hit: Optional[bool] = None
 
 
 @dataclasses.dataclass
@@ -86,6 +90,7 @@ class RunRecord:
     requests: the window's requests in order.
     window_s: the window's length (first submit to last answer).
     trace:    ``bench/tracereduce.reduce`` of the traced window, or None.
+    spans:    ``bench/spanreduce.reduce`` of the same trace, or None.
     """
 
     workload: str
@@ -95,6 +100,7 @@ class RunRecord:
     requests: List[Request]
     window_s: float
     trace: Optional[dict] = None
+    spans: Optional[dict] = None
 
 
 # --------------------------------------------------------------------------
@@ -154,7 +160,9 @@ def submit(svc, fp, config, app, kwargs, **kw) -> Request:
     lat = time.perf_counter() - t0
     m = h.metrics
     return Request(app, kwargs, lat, meta["iterations"], m.t_total_ms,
-                   m.t_execute_ms, np.asarray(props))
+                   m.t_execute_ms, np.asarray(props),
+                   iteration_traces=getattr(m, "iteration_traces", None),
+                   executor_hit=getattr(m, "executor_hit", None))
 
 
 @contextlib.contextmanager
@@ -237,16 +245,23 @@ def pick_checked(reqs: List[Request], seed: int, per_app: dict) -> List[int]:
     return sorted(out)
 
 
-def check_answers(g, reqs, idx):
+def check_answers(g, reqs, idx, root: Path = ROOT):
     """``(app, number)`` readings of the compared answers."""
     cache: dict = {}
     return [(reqs[i].app, compare.reading(g, reqs[i].app, reqs[i].kwargs,
-                                          reqs[i].answer, cache))
+                                          reqs[i].answer, cache, root))
             for i in idx]
 
 
 def percentile(values, q: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
+
+
+def per_app_ms(reqs: List[Request]) -> dict:
+    out: dict = {}
+    for r in reqs:
+        out.setdefault(r.app, []).append(r.latency_s * 1e3)
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -262,6 +277,7 @@ def run_cell(bench: Bench, workload: str, seed: int, seconds: float,
 
     wl = bench.workload(workload)
     cfg = bench.config(wl["config"])
+    graphgen.family(cfg, bench.root)
     mix = bench.traffic(wl["traffic"])
     traffic.validate(mix)
     metric_entries = bench.metrics(workload, trace)
@@ -271,7 +287,7 @@ def run_cell(bench: Bench, workload: str, seed: int, seconds: float,
     setup = {}
 
     t = time.perf_counter()
-    g = graphgen.make_graph(cfg, seed)
+    g = graphgen.make_graph(cfg, seed, bench.root)
     setup["generate_s"] = time.perf_counter() - t
     log(f"generate: {cfg['name']} V={g.num_vertices} E={g.num_edges} "
         f"({setup['generate_s']:.2f}s)")
@@ -337,15 +353,18 @@ def run_cell(bench: Bench, workload: str, seed: int, seconds: float,
     del svc, store, bundle
     gc.collect()
 
-    summary = None
+    summary = spans = None
     if trace:
-        from bench import tracereduce
+        from bench import spanreduce, tracereduce
         t = time.perf_counter()
-        summary = tracereduce.reduce(tracereduce.load(tdir),
-                                     n_devices=len(devs))
+        pd = tracereduce.load(tdir)
+        summary = tracereduce.reduce(pd, n_devices=len(devs))
+        spans = spanreduce.reduce(pd, len(devs))
+        del pd
         shutil.rmtree(tdir, ignore_errors=True)
         log(f"trace reduced in {time.perf_counter() - t:.2f}s: "
-            f"{ {k: v for k, v in summary.items() if k != 'breakdown'} }")
+            f"{ {k: v for k, v in summary.items() if k != 'breakdown'} }; "
+            f"idle by innermost span {spans['idle_s']}")
 
     ok = [r for r in reqs if r.error is None]
     failed = len(reqs) - len(ok)
@@ -356,14 +375,18 @@ def run_cell(bench: Bench, workload: str, seed: int, seconds: float,
         + ", ".join(f"{a}={sum(r.app == a for r in reqs)}"
                     for a in sorted({r.app for r in reqs}))
         + f"; pagerank iterations "
-        f"{sorted({r.iterations for r in ok if r.app == 'pagerank'})}")
+        f"{sorted({r.iterations for r in ok if r.app == 'pagerank'})}; "
+        "median ms " + ", ".join(
+            f"{a}={percentile(ms, 50):.1f}" for a, ms in sorted(
+                per_app_ms(ok).items())))
 
     # the comparison, after the window and with the program's state freed
     t = time.perf_counter()
     ref_g = reference.Graph(g.num_vertices, g.src, g.dst, g.weights)
     idx = pick_checked(reqs, seed, mix.get("check_per_app", {}))
-    values = compare.worst(check_answers(ref_g, reqs, idx))
-    correct, checks = compare.verdict(values, failed)
+    values = compare.worst(check_answers(ref_g, reqs, idx, bench.root),
+                           bench.root)
+    correct, checks = compare.verdict(values, failed, bench.root)
     log(f"compared {len(idx)} answers in {time.perf_counter() - t:.2f}s")
 
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
@@ -373,7 +396,7 @@ def run_cell(bench: Bench, workload: str, seed: int, seconds: float,
         device["busy_s"] = summary["busy_s"]
         device["window_s"] = summary["window_s"]
         record = RunRecord(workload, devs[0].device_kind, setup, plan, reqs,
-                           window_s, summary)
+                           window_s, summary, spans)
         for m in metric_entries:
             v = readers[m["name"]](record)
             if v is not None:

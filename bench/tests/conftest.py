@@ -1,5 +1,6 @@
-"""A small benchmark tree for CPU tests: the repo's traffic mixes and
-metric readers, one tiny R-MAT configuration and one cell on it."""
+"""A small benchmark tree for CPU tests: the repo's traffic mixes, graph
+families, apps and metric readers, one tiny R-MAT configuration and one
+cell on it."""
 import json
 import shutil
 from pathlib import Path
@@ -12,7 +13,7 @@ TINY_CELL = "tiny.analytics"
 
 def make_root(dest: Path, scale: int = 10) -> Path:
     (dest / "bench" / "configs").mkdir(parents=True)
-    for sub in ("traffic", "metrics"):
+    for sub in ("traffic", "metrics", "generators", "apps"):
         shutil.copytree(ROOT / "bench" / sub, dest / "bench" / sub)
     cfg = json.loads((ROOT / "bench/configs/rmat-19-32.json").read_text())
     cfg.update(name="tiny", scale=scale, edge_factor=16)

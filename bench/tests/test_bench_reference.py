@@ -90,12 +90,18 @@ def test_wcc_violations_are_zero_exactly_for_a_fixpoint(small, wcc_answer,
     assert (reference.wcc_violations(rg, changed) == 0) is ok
 
 
+def _edges(generator, scale, edge_factor, structure_seed, seed):
+    return graphgen.edges({"name": "t", "generator": generator,
+                           "scale": scale, "edge_factor": edge_factor,
+                           "structure_seed": structure_seed}, seed)[1:]
+
+
 @pytest.mark.parametrize("generator", ["rmat", "uniform"])
 def test_generator_is_fixed_by_the_seed(generator):
-    a = graphgen.edges(generator, 9, 8, 1, SEED)
-    b = graphgen.edges(generator, 9, 8, 1, SEED)
-    c = graphgen.edges(generator, 9, 8, 1, SEED + 1)
-    d = graphgen.edges(generator, 9, 8, 2, SEED)
+    a = _edges(generator, 9, 8, 1, SEED)
+    b = _edges(generator, 9, 8, 1, SEED)
+    c = _edges(generator, 9, 8, 1, SEED + 1)
+    d = _edges(generator, 9, 8, 2, SEED)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(x, y)
     # another seed: the same edges and ids, other weights; another
@@ -112,8 +118,8 @@ def test_generator_is_fixed_by_the_seed(generator):
 
 
 def test_rmat_keeps_the_graph500_skew():
-    src, _, _ = graphgen.edges("rmat", 12, 16, 1, SEED)
-    usrc, _, _ = graphgen.edges("uniform", 12, 16, 1, SEED)
+    src, _, _ = _edges("rmat", 12, 16, 1, SEED)
+    usrc, _, _ = _edges("uniform", 12, 16, 1, SEED)
     deg = np.bincount(src, minlength=4096)
     udeg = np.bincount(usrc, minlength=4096)
     assert deg.max() > 10 * udeg.max()

@@ -126,6 +126,17 @@ def test_readers_on_the_synthetic_trace():
         == pytest.approx(_read("kernel.share_pct", rec) - 100 * 50 / 1400)
 
 
+def test_executor_hit_rate_reads_the_requests_that_carry_a_hit():
+    rec = _record(None)
+    assert _read("service.executor_hit_rate", rec) is None
+    rec.requests[0].executor_hit = False
+    assert _read("service.executor_hit_rate", rec) == 0.0
+    rec.requests[1].executor_hit = True
+    assert _read("service.executor_hit_rate", rec) == 0.5
+    rec.requests[1].error = "TimeoutError: "
+    assert _read("service.executor_hit_rate", rec) == 0.0
+
+
 def test_a_trace_without_program_spans_reads_nothing():
     """The trace of a program from before the spans and kernel kinds:
     every new reader reads nothing, and none raises."""
